@@ -213,7 +213,7 @@ def sweep_paged_kv_dtype(
                 pool_bytes=pool_bytes,
             )
             if backend.impl == "pallas_paged" and scales is not None:
-                assert not _materializes_window(
+                assert not ops.materializes(
                     call, (s, w * bs, hkv, d)
                 ), f"{kv_dtype} pallas_paged materialized the gathered window"
     for impl in sorted({i for i, _ in per_tok}):
@@ -233,29 +233,6 @@ def sweep_paged_kv_dtype(
             f"int8 paged reads {ratio:.2f}x the fp32 bytes/token for "
             f"{impl} (compression target: <= 0.55x)"
         )
-
-
-def _materializes_window(call, shape) -> bool:
-    """True if any intermediate in ``call``'s jaxpr has ``shape`` — the
-    gathered-operand probe from tests/test_paged_kernel.py, applied to the
-    quantized kernel here so the bench's perf claim carries its own
-    structural check."""
-    import jax
-
-    def walk(jaxpr, acc):
-        for eqn in jaxpr.eqns:
-            acc.extend(v.aval for v in eqn.outvars)
-            for val in eqn.params.values():
-                items = val if isinstance(val, (tuple, list)) else [val]
-                for item in items:
-                    if isinstance(item, jax.core.ClosedJaxpr):
-                        walk(item.jaxpr, acc)
-                    elif isinstance(item, jax.core.Jaxpr):
-                        walk(item, acc)
-        return acc
-
-    avals = walk(jax.make_jaxpr(call)().jaxpr, [])
-    return any(getattr(a, "shape", None) == tuple(shape) for a in avals)
 
 
 def sweep_matmul(records: List[Dict[str, Any]], impl_filter: Optional[str]) -> None:
@@ -337,4 +314,7 @@ def main(argv: Optional[List[str]] = None) -> bool:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -12,6 +12,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         accuracy_bitwidth,
         fig3_efficiency,

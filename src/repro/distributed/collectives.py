@@ -15,11 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PyTree = Any
 
 
@@ -55,7 +50,7 @@ def compressed_grad_allreduce(
         )
 
     specs = jax.tree.map(lambda _: P(), grads)
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda g, e: _split_pairs(body(g, e)),
         mesh=mesh,
         in_specs=(specs, specs),

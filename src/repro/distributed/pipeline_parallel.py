@@ -19,20 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _pvary(x: jax.Array, axis: str) -> jax.Array:
-    """Mark ``x`` stage-varying for shard_map's vma typing (jax >= 0.6's
-    ``lax.pcast``); older jax tracks replication itself — no-op there."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:  # pragma: no cover - version-dependent
-        return x
-    return pcast(x, (axis,), to="varying")
-
 PyTree = Any
 
 
@@ -55,8 +41,9 @@ def pipeline_apply(
         idx = jax.lax.axis_index(axis)
         mb = xs.shape[1:]
         # mark carries stage-varying up front (shard_map vma typing)
-        buf = _pvary(jnp.zeros(mb, xs.dtype), axis)
-        outs = _pvary(jnp.zeros((m,) + mb, xs.dtype), axis)
+        buf = jax.lax.pcast(jnp.zeros(mb, xs.dtype), (axis,), to="varying")
+        outs = jax.lax.pcast(jnp.zeros((m,) + mb, xs.dtype), (axis,),
+                             to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -85,7 +72,7 @@ def pipeline_apply(
         return outs
 
     pspecs = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = _shard_map(
+    fn = jax.shard_map(
         stage_program,
         mesh=mesh,
         in_specs=(pspecs, P()),

@@ -9,6 +9,7 @@ step array is computed in ops.py (calibration) and streamed per grid cell.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,8 +53,11 @@ def crossbar_matmul_pallas(
     *,
     spec: CrossbarSpec = DEFAULT_SPEC,
     block_m: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None -> ops.platform decides
 ) -> jax.Array:
+    from repro.ops.platform import resolve_interpret  # ops imports kernels
+
+    interpret = resolve_interpret(interpret)
     m, kdim = xq.shape
     _, n = wq.shape
     ktiles = kdim // spec.tile_rows

@@ -176,9 +176,12 @@ def flash_star_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     pv_int8: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None -> ops.platform decides
 ) -> jax.Array:
     """Fused attention, heads-major layout.  Returns [B, Hq, Tq, D]."""
+    from repro.ops.platform import resolve_interpret  # ops imports kernels
+
+    interpret = resolve_interpret(interpret)
     batch, hq, tq, d = q.shape
     _, hkv, tk, _ = k.shape
     assert hq % hkv == 0, "GQA needs Hq % Hkv == 0"

@@ -82,8 +82,11 @@ def ssd_scan_pallas(
     cmat: jax.Array,  # [B, T, N]
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None -> ops.platform decides
 ) -> Tuple[jax.Array, jax.Array]:
+    from repro.ops.platform import resolve_interpret  # ops imports kernels
+
+    interpret = resolve_interpret(interpret)
     b, t, h, p = xdt.shape
     n = bmat.shape[-1]
     pad = (-t) % chunk

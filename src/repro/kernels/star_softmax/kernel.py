@@ -34,6 +34,13 @@ from repro.hwmodel import faults as faults_lib
 from repro.hwmodel.faults import FaultModel
 
 
+def _levels_column(nl: int) -> jax.Array:
+    """``[0, 1, ..., nl-1]`` as an ``(nl, 1)`` float32 column.  Mosaic's
+    iota yields integers only, so the LUT index is built in int32 and
+    cast."""
+    return jax.lax.broadcasted_iota(jnp.int32, (nl, 1), 0).astype(jnp.float32)
+
+
 def _kernel(
     x_ref,
     o_ref,
@@ -56,7 +63,7 @@ def _kernel(
         # Faithful crossbar dataflow: one-hot match matrix x LUT column (MXU).
         levels = jax.lax.broadcasted_iota(jnp.int32, (br, d, nl), 2)
         onehot = (levels == k[..., None]).astype(jnp.float32)
-        lut = jnp.exp(-jax.lax.broadcasted_iota(jnp.float32, (nl, 1), 0) / scale)
+        lut = jnp.exp(-_levels_column(nl) / scale)
         p = jax.lax.dot_general(
             onehot.reshape(br * d, nl), lut,
             (((1,), (0,)), ((), ())),
@@ -70,7 +77,7 @@ def _kernel(
         # counter + VMM: histogram the match indices, then one small VMM.
         levels = jax.lax.broadcasted_iota(jnp.int32, (br, d, nl), 2)
         counts = jnp.sum((levels == k[..., None]).astype(jnp.float32), axis=1)
-        lut = jnp.exp(-jax.lax.broadcasted_iota(jnp.float32, (nl, 1), 0) / scale)
+        lut = jnp.exp(-_levels_column(nl) / scale)
         den = jax.lax.dot_general(
             counts, lut, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -147,18 +154,22 @@ def star_softmax_pallas(
     block_rows: int = 8,
     use_histogram: bool = False,
     use_mxu_lut: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     fault: Optional[FaultModel] = None,
 ) -> jax.Array:
     """STAR softmax over the last axis of ``x`` (any leading shape).
 
     Rows are padded to a multiple of ``block_rows``; the full feature dim
     lives in one VMEM tile (use ``flash_star`` for attention-scale rows).
+    ``interpret=None`` asks the platform (``ops.platform``).
 
     ``fault`` (static, hashable) switches to the fault-injected kernel:
     the seeded CAM/LUT/VMM realizations stream in as operands and the ADC
     denominator gain applies on the way out (DESIGN.md §9).
     """
+    from repro.ops.platform import resolve_interpret  # ops imports kernels
+
+    interpret = resolve_interpret(interpret)
     orig_shape = x.shape
     d = orig_shape[-1]
     rows = 1

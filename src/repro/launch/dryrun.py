@@ -1,11 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU tool: pinned off any attached chip, for itself and the per-cell
+# children it spawns (they inherit the environment)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 mesh; record memory/cost analysis + roofline terms.
 
-MUST be run as its own process (the two lines above lock jax to 512 host
-devices before any other import — never set that flag globally).
+MUST be run as its own process (the lines above lock jax to 512 host CPU
+devices before any other import — never set that flag globally).  The
+production mesh it compiles for is made of TPU v5e chips, whose peaks the
+roofline terms use.
 
   python -m repro.launch.dryrun --arch granite_8b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both --out experiments/dryrun
@@ -19,6 +24,8 @@ import sys  # noqa: E402
 import time  # noqa: E402
 import traceback  # noqa: E402
 from typing import Any, Dict, Optional  # noqa: E402
+
+TARGET_DEVICE_KIND = "TPU v5 lite"  # the production mesh's chips
 
 
 def _probe_depths(cfg) -> tuple:
@@ -150,8 +157,6 @@ def _run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Optional[str]
     def costs_of(compiled):
         out = {"flops": 0.0, "bytes": 0.0, "coll": 0.0, "coll_detail": {}}
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per device
-            ca = ca[0] if ca else {}
         out["flops"] = float(ca.get("flops", 0.0))
         out["bytes"] = float(ca.get("bytes accessed", 0.0))
         coll = collective_bytes(compiled.as_text())
@@ -234,6 +239,7 @@ def _run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Optional[str]
     )
     rec.update(
         roofline_terms(
+            device_kind=TARGET_DEVICE_KIND,
             flops_per_dev=rec["flops_per_dev"],
             bytes_per_dev=rec["bytes_per_dev"],
             coll_bytes_per_dev=rec["coll_bytes_per_dev"],

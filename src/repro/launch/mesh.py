@@ -3,11 +3,8 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state.  Single pod: 16x16 = 256 chips (data, model).
 Multi-pod: 2 pods x 256 = 512 chips (pod, data, model) — "pod" is the
-slowest-varying axis (DCN-friendly outer data axis).
-
-``jax.sharding.AxisType`` only exists from jax 0.5 (explicit-sharding
-meshes); on older jax every mesh axis is Auto-typed anyway, so the
-``axis_types`` kwarg is simply dropped there.
+slowest-varying axis (DCN-friendly outer data axis).  Every axis is
+``AxisType.Auto``: XLA's sharding propagation places the collectives.
 """
 
 from __future__ import annotations
@@ -15,17 +12,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _mesh_kwargs(num_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * num_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,12 +25,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, found {len(devs)} "
             "(dryrun.py must set XLA_FLAGS before any jax import)"
         )
-    return jax.make_mesh(shape, axes, devices=devs[:n], **_mesh_kwargs(len(axes)))
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_mesh(shape, axes, devices=None):
     """Generic helper for tests/examples (Auto axis types)."""
     devs = devices if devices is not None else jax.devices()[: int(np.prod(shape))]
     return jax.make_mesh(
-        tuple(shape), tuple(axes), devices=devs, **_mesh_kwargs(len(axes))
+        tuple(shape), tuple(axes), devices=devs,
+        axis_types=(AxisType.Auto,) * len(axes),
     )

@@ -1,10 +1,11 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch, shape, mesh), all in seconds (TPU v5e constants):
+Three terms per (arch, shape, mesh), all in seconds, against the peaks of
+the chip named by its ``device_kind`` (:data:`PEAKS`):
 
-  compute    = HLO_FLOPs_per_dev / PEAK_FLOPS
-  memory     = HLO_bytes_per_dev / HBM_BW
-  collective = collective_bytes_per_dev / LINK_BW
+  compute    = HLO_FLOPs_per_dev / bf16 FLOP/s
+  memory     = HLO_bytes_per_dev / HBM bytes/s
+  collective = collective_bytes_per_dev / bytes/s of one ICI link
 
 HLO_FLOPs / bytes come from ``compiled.cost_analysis()`` (per-partition for
 SPMD modules).  Collective bytes are NOT in cost_analysis: we parse the
@@ -20,10 +21,29 @@ from typing import Any, Dict
 
 import numpy as np
 
-# TPU v5e
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s
-LINK_BW = 50e9  # bytes/s per ICI link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interchip
+# interconnect over the 4 links of a 2-D torus -> 50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "ici_link_bytes_per_s": 50e9,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table row for ``device_kind``; a chip not in the table is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (add a row with its source)"
+        ) from None
 
 COLLECTIVE_OPS = (
     "all-gather",
@@ -116,13 +136,15 @@ def model_flops(n_params: int, n_active_params: int, tokens: int, kind: str) -> 
 
 def roofline_terms(
     *,
+    device_kind: str,
     flops_per_dev: float,
     bytes_per_dev: float,
     coll_bytes_per_dev: float,
-) -> Dict[str, float]:
-    t_c = flops_per_dev / PEAK_FLOPS
-    t_m = bytes_per_dev / HBM_BW
-    t_x = coll_bytes_per_dev / LINK_BW
+) -> Dict[str, Any]:
+    peak = peaks(device_kind)
+    t_c = flops_per_dev / peak["bf16_flops"]
+    t_m = bytes_per_dev / peak["hbm_bytes_per_s"]
+    t_x = coll_bytes_per_dev / peak["ici_link_bytes_per_s"]
     dominant = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     bound = max(t_c, t_m, t_x)
     return {

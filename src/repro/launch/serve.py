@@ -36,6 +36,7 @@ process-global dispatch/guard counters).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import sys
 import time
 
@@ -98,23 +99,63 @@ def run_lockstep(args, cfg, params) -> int:
     return 0
 
 
-def run_continuous(args, cfg, params) -> int:
+@contextlib.contextmanager
+def serving(cfg, cb_cfg=None, *, attn_impl=None, softmax_impl=None,
+            params=None, seed=0):
+    """The serve path's set-up, shared by :func:`main` and ``chip_smoke.py``.
+
+    Validates the config's specs against the registry (before any
+    lowering), enters the ``ops.use`` backend overrides, materializes
+    seeded random params unless ``params`` is given, and builds a
+    :class:`~repro.serve.engine.ContinuousBatchingEngine` when ``cb_cfg``
+    is given.  Yields ``(params, engine)``; the overrides stay active for
+    the whole block, because the engine's programs are traced on first
+    use.
+
+    ``attn_impl="pallas_paged"`` is the gather-free paged decode kernel
+    (DESIGN.md §11): it flips the serve stack to the block-pool cache and
+    retargets the paged op, while dense invocations (prefill, lockstep)
+    keep the ``paged`` marker's xla math.
+    """
+    import jax
+
+    from repro import ops
+    from repro.models.param import materialize
+    from repro.models.registry import build_model
+    from repro.serve.engine import ContinuousBatchingEngine
+
+    kv_dtype = cb_cfg.kv_dtype if cb_cfg is not None else "fp32"
+    overrides = {}
+    if attn_impl == "pallas_paged":
+        ops.validate(
+            cfg.paged_attention_spec, impl="pallas_paged", kv_dtype=kv_dtype,
+        )
+        overrides["paged_attention"] = "pallas_paged"
+        attn_impl = "paged"
+    elif kv_dtype != "fp32":
+        # quantized pages: fail at config time if the resolved paged
+        # backend cannot dequantize this layout (DESIGN.md §13)
+        ops.validate(cfg.paged_attention_spec, kv_dtype=kv_dtype)
+    ops.validate(cfg.attention_spec, impl=attn_impl or cfg.attention_spec.impl)
+    ops.validate(cfg.softmax_spec, impl=softmax_impl or cfg.softmax_spec.impl)
+    if attn_impl:
+        overrides["attention"] = attn_impl
+    if softmax_impl:
+        overrides["softmax"] = softmax_impl
+    with ops.use(**overrides):
+        if params is None:
+            params = materialize(
+                build_model(cfg).param_specs(), jax.random.PRNGKey(seed))
+        engine = (
+            ContinuousBatchingEngine(cfg, params, cb_cfg)
+            if cb_cfg is not None else None
+        )
+        yield params, engine
+
+
+def run_continuous(args, cfg, eng) -> int:
     import numpy as np
 
-    from repro.serve.engine import ContinuousBatchingEngine, ContinuousConfig
-
-    max_len = args.max_len or (args.prompt_len + args.gen + cfg.num_patches + 8)
-    eng = ContinuousBatchingEngine(
-        cfg, params,
-        ContinuousConfig(num_slots=args.slots, max_len=max_len,
-                         temperature=args.temperature,
-                         kv_layout=args.kv_layout,
-                         kv_block_size=args.kv_block_size,
-                         kv_pool_blocks=args.kv_pool_blocks,
-                         kv_dtype=args.kv_dtype,
-                         prefix_cache=args.prefix_cache,
-                         prefill_chunk_tokens=args.prefill_chunk_tokens),
-    )
     rng = np.random.default_rng(0)
     total = 0
     for i in range(args.requests):
@@ -223,48 +264,35 @@ def main() -> int:
     )
     args = ap.parse_args()
 
-    import jax
+    from repro import obs
+    from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve.engine import ContinuousConfig
 
-    from repro import obs, ops
-
+    enable_compile_cache()
     if args.trace_out:
         # install before the engine is built — engines bind the global
         # tracer at construction
         obs.enable_tracing()
-    from repro.configs import get_config, get_smoke_config
-    from repro.models.param import materialize
-    from repro.models.registry import build_model
-
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    attn_impl = args.attn_impl
-    overrides = {}
-    if attn_impl == "pallas_paged":
-        # the gather-free paged decode kernel (DESIGN.md §11): flip the
-        # serve stack to the block-pool cache and retarget the paged op;
-        # dense invocations (prefill, lockstep) keep the marker's xla math
-        ops.validate(
-            cfg.paged_attention_spec, impl="pallas_paged",
+    cb_cfg = None
+    if args.engine == "continuous":
+        cb_cfg = ContinuousConfig(
+            num_slots=args.slots,
+            max_len=args.max_len or (
+                args.prompt_len + args.gen + cfg.num_patches + 8),
+            temperature=args.temperature,
+            kv_layout=args.kv_layout,
+            kv_block_size=args.kv_block_size,
+            kv_pool_blocks=args.kv_pool_blocks,
             kv_dtype=args.kv_dtype,
+            prefix_cache=args.prefix_cache,
+            prefill_chunk_tokens=args.prefill_chunk_tokens,
         )
-        overrides["paged_attention"] = "pallas_paged"
-        attn_impl = "paged"
-    elif args.kv_dtype != "fp32":
-        # quantized pages: fail at config time if the resolved paged
-        # backend cannot dequantize this layout (DESIGN.md §13)
-        ops.validate(cfg.paged_attention_spec, kv_dtype=args.kv_dtype)
-    # fail fast on a spec the registry cannot serve, before any lowering
-    ops.validate(cfg.attention_spec, impl=attn_impl or cfg.attention_spec.impl)
-    ops.validate(cfg.softmax_spec, impl=args.softmax_impl or cfg.softmax_spec.impl)
-
-    if attn_impl:
-        overrides["attention"] = attn_impl
-    if args.softmax_impl:
-        overrides["softmax"] = args.softmax_impl
-    with ops.use(**overrides):
-        model = build_model(cfg)
-        params = materialize(model.param_specs(), jax.random.PRNGKey(0))
-        if args.engine == "continuous":
-            return run_continuous(args, cfg, params)
+    with serving(cfg, cb_cfg, attn_impl=args.attn_impl,
+                 softmax_impl=args.softmax_impl) as (params, engine):
+        if engine is not None:
+            return run_continuous(args, cfg, engine)
         return run_lockstep(args, cfg, params)
 
 

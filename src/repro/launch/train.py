@@ -46,10 +46,12 @@ def main() -> int:
     import jax
 
     from repro.configs import get_config, get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh
     from repro.train.loop import LoopConfig, run_train
     from repro.train.step import TrainConfig
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = None
     if args.mesh:

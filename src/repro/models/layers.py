@@ -212,10 +212,13 @@ def attention_block(
 
     The cache comes in three shapes: a scalar-``len`` decode cache, a
     per-slot pool (``len`` is a ``[B]`` vector), and a *paged* pool —
-    K/V are ``[num_blocks, block_size, Hkv, D]`` page pools plus a
+    K/V are every layer's ``[L, num_blocks, block_size, Hkv, D]`` page
+    pools, a ``"layer"`` index naming this layer's slab, and a
     ``"tables"`` entry of per-slot block tables (``repro.serve.paged``),
     with ``paged_cache_t`` carrying the logical per-slot row count (a
-    static int: it sizes the gathered view and the ring modulo).
+    static int: it sizes the gathered view and the ring modulo).  The
+    whole pool travels so the one-row writes update it in place; a
+    per-layer slice would copy the layer's pages.
 
     Returns ``(out, cache', (k, v))`` — the fresh (rotated) K/V of this call
     so prefill can prime caches without recomputing projections."""
@@ -264,8 +267,9 @@ def attention_block(
         assert tq == 1, "paged cache only supports 1-token decode"
         assert paged_cache_t is not None, "paged cache requires paged_cache_t"
         cache_t = paged_cache_t
-        bs = cache["k"].shape[1]
+        bs = cache["k"].shape[2]
         tables = cache["tables"]
+        layer = cache["layer"]
         ring = sliding_window is not None and cache_t <= sliding_window
         idx = cache["len"] % cache_t if ring else cache["len"]
         # free slots' counters regrow past their (scratch-only) tables; the
@@ -291,21 +295,21 @@ def attention_block(
             k_sc = jnp.where(
                 fresh[:, None],
                 kvquant.row_scale(krow, kv_dtype),
-                cache["k_scale"][blk],
+                cache["k_scale"][layer, blk],
             )
             v_sc = jnp.where(
                 fresh[:, None],
                 kvquant.row_scale(vrow, kv_dtype),
-                cache["v_scale"][blk],
+                cache["v_scale"][layer, blk],
             )
-            ck = cache["k"].at[blk, row].set(
+            ck = cache["k"].at[layer, blk, row].set(
                 kvquant.encode(krow, k_sc[..., None], kv_dtype)
             )
-            cv = cache["v"].at[blk, row].set(
+            cv = cache["v"].at[layer, blk, row].set(
                 kvquant.encode(vrow, v_sc[..., None], kv_dtype)
             )
-            ks_pages = cache["k_scale"].at[blk].set(k_sc)
-            vs_pages = cache["v_scale"].at[blk].set(v_sc)
+            ks_pages = cache["k_scale"].at[layer, blk].set(k_sc)
+            vs_pages = cache["v_scale"].at[layer, blk].set(v_sc)
             new_cache = {
                 "k": ck, "v": cv,
                 "k_scale": ks_pages, "v_scale": vs_pages,
@@ -313,8 +317,10 @@ def attention_block(
             }
             kv_scales = (ks_pages, vs_pages)
         else:
-            ck = cache["k"].at[blk, row].set(k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[blk, row].set(v[:, 0].astype(cache["v"].dtype))
+            ck = cache["k"].at[layer, blk, row].set(
+                k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[layer, blk, row].set(
+                v[:, 0].astype(cache["v"].dtype))
             new_cache = {"k": ck, "v": cv, "len": new_len}
             kv_scales = None
         kvl = jnp.minimum(new_len, cache_t) if ring else new_len
@@ -323,7 +329,7 @@ def attention_block(
         )
         ctx = ops.paged_attention(
             q, ck, cv, tables, spec, kv_valid_len=kvl, kv_len=cache_t,
-            kv_scales=kv_scales,
+            kv_scales=kv_scales, layer=layer,
         )
         return ctx.reshape(b, tq, -1), new_cache, (k, v)
     if cache is not None:

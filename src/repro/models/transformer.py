@@ -441,16 +441,25 @@ class DecoderLM:
 
         kv_leaves = tuple(cache["layers"])  # += k/v_scale when quantized
 
+        # The page pools ride the scan *carry*, whole: each layer writes
+        # its one new row per slot in place and its attention reads its
+        # own slab through the layer index.  Scanned as xs/ys instead,
+        # the pools would be rebuilt as fresh stacked outputs — a second
+        # full pool in HBM every tick.
         def body(carry, xs):
+            h, pools = carry
             out, new_c, _, _ = self._block(
-                xs["p"], carry, positions=pos,
-                cache={**xs["c"], "len": cache["len"], "tables": block_tables},
+                xs["p"], h, positions=pos,
+                cache={**pools, "len": cache["len"], "tables": block_tables,
+                       "layer": xs["layer"]},
                 kv_valid_len=None, paged_cache_t=cache_t,
             )
-            return out, {name: new_c[name] for name in kv_leaves}
+            return (out, {name: new_c[name] for name in kv_leaves}), None
 
-        h, new_layer_caches = L.scan_blocks(
-            body, x, {"p": params["blocks"], "c": cache["layers"]}
+        (h, new_layer_caches), _ = L.scan_blocks(
+            body, (x, cache["layers"]),
+            {"p": params["blocks"],
+             "layer": jnp.arange(cfg.num_layers, dtype=jnp.int32)},
         )
         h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = L.unembed(params["unembed"], h, cfg, params["embed"])

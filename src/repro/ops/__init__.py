@@ -67,4 +67,4 @@ from repro.ops.specs import (  # noqa: F401
 
 # Importing the built-in backends populates the registry as a side effect.
 from repro.ops import impls as _impls  # noqa: E402,F401  isort: skip
-from repro.ops.impls import paged_gather_bytes  # noqa: E402,F401  isort: skip
+from repro.ops.impls import materializes, paged_gather_bytes  # noqa: E402,F401  isort: skip

@@ -61,11 +61,13 @@ def resolve(spec: Spec, **overrides: Any) -> Tuple[Backend, Spec]:
     spec = dataclasses.replace(spec, **updates)
     backend = registry.get(spec.op, spec.impl)
     registry.validate(backend, spec)
-    # per-(op, resolved impl) dispatch counter (DESIGN.md §10).  Counts
-    # *dispatches*: for a jitted call site that is trace time, so a cached
-    # retrace-free loop counts once — which is itself a useful signal.
+    # per-(op, resolved impl, resolved interpret) dispatch counter
+    # (DESIGN.md §10).  Counts *dispatches*: for a jitted call site that is
+    # trace time, so a cached retrace-free loop counts once — which is
+    # itself a useful signal.  The interpret label shows a Pallas kernel
+    # that fell back to the interpreter on a chip.
     _obs_registry().counter("ops.dispatch.calls").inc(
-        op=spec.op, impl=backend.impl
+        op=spec.op, impl=backend.impl, interpret=spec.interpret
     )
     return backend, spec
 
@@ -124,8 +126,8 @@ def attention(
 
 def paged_attention(
     q: jax.Array,  # [S, Tq, Hq, D] (decode: Tq == 1)
-    k_pages: jax.Array,  # [num_blocks, block_size, Hkv, D]
-    v_pages: jax.Array,  # [num_blocks, block_size, Hkv, D]
+    k_pages: jax.Array,  # [num_blocks, block_size, Hkv, D] (or [L, ...])
+    v_pages: jax.Array,  # same shape as k_pages
     block_tables: jax.Array,  # [S, W] int32 block ids per sequence
     spec: Optional[PagedAttentionSpec] = None,
     *,
@@ -133,6 +135,7 @@ def paged_attention(
     kv_len: Optional[int] = None,  # logical gathered length (<= W * block_size)
     scale: Optional[float] = None,
     kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,  # ([N,H], [N,H])
+    layer: Optional[jax.Array] = None,  # int32: which of [L, ...] pools
     **overrides: Any,
 ) -> jax.Array:
     """Paged-KV decode attention: gather each sequence's blocks through its
@@ -148,6 +151,10 @@ def paged_attention(
     (``spec.kv_dtype != "fp32"`` — DESIGN.md §13); required then,
     forbidden otherwise, so a layout/spec mismatch fails loudly here
     instead of decoding garbage.
+
+    ``layer`` reads one layer of pools stacked over layers
+    (``[L, num_blocks, ...]`` pages, ``[L, num_blocks, H]`` scales) in
+    place: the serve tick carries the whole stack.
     """
     backend, spec = resolve(
         spec if spec is not None else DEFAULT_PAGED_ATTENTION, **overrides
@@ -169,6 +176,7 @@ def paged_attention(
         kv_len=kv_len,
         scale=scale,
         kv_scales=kv_scales,
+        layer=layer,
     )
 
 

@@ -366,7 +366,12 @@ def _make_paged_backend(impl: str, dense_fn):
         kv_len: Optional[int] = None,
         scale: Optional[float] = None,
         kv_scales: Optional[tuple] = None,
+        layer: Optional[jax.Array] = None,
     ) -> jax.Array:
+        if layer is not None:
+            k_pages, v_pages = k_pages[layer], v_pages[layer]
+            if kv_scales is not None:
+                kv_scales = tuple(sc[layer] for sc in kv_scales)
         kd, vd = _gather_pages(k_pages, v_pages, block_tables, kv_len, kv_scales)
         return dense_fn(
             _paged_dense_spec(spec, impl),
@@ -424,6 +429,7 @@ def _paged_pallas_paged(
     kv_len: Optional[int] = None,
     scale: Optional[float] = None,
     kv_scales: Optional[tuple] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Gather-free decode: the kernel walks the block table in place."""
     if q.shape[1] != 1:
@@ -448,6 +454,7 @@ def _paged_pallas_paged(
         interpret=spec.interpret,
         k_scale=k_scale,
         v_scale=v_scale,
+        layer=layer,
     )
     return out[:, None]
 
@@ -463,7 +470,7 @@ register(
         "kv_dtype": kvquant.KV_DTYPES,
     },
     description="gather-free scalar-prefetch decode kernel: the grid "
-    "walks (slot, kv_head, kv_block) and DMA-fetches only table-named "
+    "walks (slot, kv_block) and DMA-fetches only table-named "
     "pages; quantized pools dequantize in-kernel with the scale pages "
     "riding scalar prefetch (kernels.paged_attention)",
 )
@@ -502,6 +509,32 @@ def paged_gather_bytes(
     else:
         blocks = len(lens) * table_width
     return blocks * (block_size * row_bytes + scale_bytes_per_block)
+
+
+def materializes(fn, shape, *args) -> bool:
+    """True if any intermediate of ``fn(*args)``'s jaxpr — nested jaxprs
+    (jit, scan, pallas bodies) included — has exactly ``shape``.
+
+    The structural form of the gather-free claim: ``pallas_paged`` must
+    never build the ``[S, W*bs, Hkv, D]`` gathered window that every
+    gather adapter does."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    shape = tuple(shape)
+
+    def walk(jaxpr) -> bool:
+        for eqn in jaxpr.eqns:
+            if any(getattr(v.aval, "shape", None) == shape for v in eqn.outvars):
+                return True
+            for val in eqn.params.values():
+                for item in val if isinstance(val, (tuple, list)) else (val,):
+                    if isinstance(item, ClosedJaxpr):
+                        item = item.jaxpr
+                    if isinstance(item, Jaxpr) and walk(item):
+                        return True
+        return False
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 # ---------------------------------------------------------------------------

@@ -195,6 +195,7 @@ class TokenEvent:
     token: int
     index: int  # 0-based position within the request's generation
     finished: bool
+    slot: int  # the slot that produced it (its row in ``last_logits``)
 
 
 class ContinuousBatchingEngine:
@@ -388,6 +389,9 @@ class ContinuousBatchingEngine:
         self._inputs = np.zeros((cb_cfg.num_slots, 1), np.int32)  # next token per slot
         self._frontend: Dict[int, Dict[str, jax.Array]] = {}
         self.ticks = 0  # decode ticks executed (for utilization accounting)
+        # the last decode tick's [S, V] logits, left on device: a decode
+        # event's logits are ``last_logits[ev.slot]`` (parity checks)
+        self.last_logits: Optional[jax.Array] = None
         self._tick = self._build_tick()
 
     def _build_tick(self):
@@ -518,7 +522,7 @@ class ContinuousBatchingEngine:
     def _emit(self, slot: Slot, token: int, finished: bool) -> TokenEvent:
         req = slot.request
         index = len(req.generated_prefix) + len(slot.generated) - 1
-        ev = TokenEvent(req.uid, token, index, finished)
+        ev = TokenEvent(req.uid, token, index, finished, slot.index)
         now = self._clock()
         if req.first_token_time is None:
             if req.submit_time is not None:
@@ -1103,6 +1107,7 @@ class ContinuousBatchingEngine:
                 self.params, self.pool, jnp.asarray(self._inputs),
                 tables, uids, steps,
             )
+            self.last_logits = last
             self._m_h2d.inc(self._inputs.size * 4)
             if paged:
                 for slot in active:

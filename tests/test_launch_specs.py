@@ -71,13 +71,18 @@ def test_collective_parser_skips_done_ops():
 
 def test_roofline_terms_math():
     t = roofline_terms(
+        device_kind="TPU v5 lite",
         flops_per_dev=197e12, bytes_per_dev=819e9, coll_bytes_per_dev=0.0
     )
     assert t["t_compute_s"] == pytest.approx(1.0)
     assert t["t_memory_s"] == pytest.approx(1.0)
     assert t["dominant"] in ("compute", "memory")
-    t2 = roofline_terms(flops_per_dev=1e12, bytes_per_dev=1e9, coll_bytes_per_dev=1e12)
+    t2 = roofline_terms(device_kind="TPU v5 lite", flops_per_dev=1e12,
+                        bytes_per_dev=1e9, coll_bytes_per_dev=1e12)
     assert t2["dominant"] == "collective"
+    with pytest.raises(ValueError, match="TPU v9"):
+        roofline_terms(device_kind="TPU v9", flops_per_dev=1.0,
+                       bytes_per_dev=1.0, coll_bytes_per_dev=0.0)
 
 
 def test_model_flops_train_vs_infer():
@@ -89,3 +94,29 @@ def test_production_mesh_requires_devices():
     from repro.launch.mesh import make_production_mesh
     with pytest.raises(RuntimeError):
         make_production_mesh()  # only 1 device in the test process
+
+
+@pytest.mark.parametrize("env", [None, "/somewhere/else"])
+def test_compile_cache_dir(monkeypatch, env):
+    """The environment's directory wins untouched; otherwise the fixed
+    ``<checkout>/.jax_cache``."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.CHECKOUT_CACHE_DIR == checkout / ".jax_cache"
+    was = jax.config.jax_compilation_cache_dir
+    sentinel = "/unchanged"
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want, config = str(checkout / ".jax_cache"), str(checkout / ".jax_cache")
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+            want, config = env, sentinel  # JAX reads the variable itself
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == config
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -291,8 +291,9 @@ def test_dispatch_counts_resolved_backend_labels():
         with ops.use(softmax="xla"):
             ops.softmax(x, kind="exact")  # resolved impl is the override
         c = mine.counter("ops.dispatch.calls")
-        assert c.value(op="softmax", impl="reference") == 1
-        assert c.value(op="softmax", impl="xla") == 1
+        here = ops.default_interpret()
+        assert c.value(op="softmax", impl="reference", interpret=here) == 1
+        assert c.value(op="softmax", impl="xla", interpret=here) == 1
     finally:
         obs.set_default_registry(prev)
 

@@ -128,6 +128,23 @@ def test_parity_gqa_ratios(hq, hkv):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
+@pytest.mark.parametrize("impl", ["reference", "pallas_paged"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_stacked_pools_read_the_named_layer(impl, layer):
+    """``layer`` reads one slab of ``[L, N, bs, Hkv, D]`` pools (the serve
+    tick's layout) exactly as the same call on that slab alone; the other
+    layers hold different values, so a wrong slab shows."""
+    q, kp, vp, tables, kvl = _operands(lens=(6, 25, 11))
+    kst = jnp.stack([kp, -kp, 2 * kp])
+    vst = jnp.stack([vp, vp + 1.0, -vp])
+    spec = _spec(impl, "star", 8)
+    want = ops.paged_attention(q, kst[layer], vst[layer], tables, spec,
+                               kv_valid_len=kvl)
+    got = ops.paged_attention(q, kst, vst, tables, spec, kv_valid_len=kvl,
+                              layer=jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_kernel_rejects_bad_gqa_and_multitoken_queries():
     q, kp, vp, tables, kvl = _operands()
     with pytest.raises(AssertionError, match="GQA"):
@@ -146,24 +163,6 @@ def test_kernel_rejects_bad_gqa_and_multitoken_queries():
 # gather-freedom: the structural no-[S, W*bs, H, D] assertion
 
 
-def _jaxpr_avals(jaxpr, acc):
-    for eqn in jaxpr.eqns:
-        for v in eqn.outvars:
-            acc.append(v.aval)
-        for val in eqn.params.values():
-            if isinstance(val, jax.core.ClosedJaxpr):
-                _jaxpr_avals(val.jaxpr, acc)
-            elif isinstance(val, jax.core.Jaxpr):
-                _jaxpr_avals(val, acc)
-            elif isinstance(val, (tuple, list)):
-                for item in val:
-                    if isinstance(item, jax.core.ClosedJaxpr):
-                        _jaxpr_avals(item.jaxpr, acc)
-                    elif isinstance(item, jax.core.Jaxpr):
-                        _jaxpr_avals(item, acc)
-    return acc
-
-
 def _materializes_gathered_operand(impl) -> bool:
     q, kp, vp, tables, kvl = _operands()
     s, w = tables.shape
@@ -173,9 +172,7 @@ def _materializes_gathered_operand(impl) -> bool:
     def call(q, kp, vp, tables, kvl):
         return ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
 
-    avals = _jaxpr_avals(jax.make_jaxpr(call)(q, kp, vp, tables, kvl), [])
-    gathered = (s, w * bs, hkv, d)
-    return any(getattr(a, "shape", None) == gathered for a in avals)
+    return ops.materializes(call, (s, w * bs, hkv, d), q, kp, vp, tables, kvl)
 
 
 def test_pallas_paged_never_materializes_the_gathered_window():

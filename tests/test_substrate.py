@@ -147,15 +147,10 @@ def test_logical_rules_divisibility_and_single_use():
     from repro.distributed.sharding import DEFAULT_RULES, logical_to_pspec
 
     del Mesh, np  # Mesh with repeated device objects is invalid; build an
-    # abstract mesh instead.  The AbstractMesh constructor changed across
-    # jax versions: <= 0.4.x takes one (name, size) pair tuple, newer
-    # takes (shape, axis_names).
+    # abstract mesh instead.
     from jax.sharding import AbstractMesh
 
-    try:
-        mesh = AbstractMesh((4, 4), ("data", "model"))
-    except TypeError:  # jax <= 0.4.x signature
-        mesh = AbstractMesh((("data", 4), ("model", 4)))
+    mesh = AbstractMesh((4, 4), ("data", "model"))
     # divisible: shard
     assert logical_to_pspec(("vocab",), (512,), DEFAULT_RULES, mesh) == P("model")
     # not divisible: auto-drop
