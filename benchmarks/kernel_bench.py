@@ -102,8 +102,9 @@ def sweep_paged_decode(
     for w in (4, 16):  # table width -> per-slot pool of w*bs rows
         n = s * w + 1  # + scratch block 0
         q = jnp.asarray(rng.normal(size=(s, 1, hq, d)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
+        # a stack of one layer's pools: [L=1, N, bs, Hkv, D]
+        kp = jnp.asarray(rng.normal(size=(1, n, bs, hkv, d)), jnp.float32)
+        vp = jnp.asarray(rng.normal(size=(1, n, bs, hkv, d)), jnp.float32)
         tables = jnp.arange(1, s * w + 1, dtype=jnp.int32).reshape(s, w)
         for live in (8, w * bs // 2, w * bs):
             kvl = jnp.full((s,), live, jnp.int32)
@@ -116,7 +117,7 @@ def sweep_paged_decode(
                 us = _t(
                     lambda: ops.paged_attention(
                         q, kp, vp, tables, spec,
-                        kv_valid_len=kvl, kv_len=w * bs,
+                        kv_valid_len=kvl, layer=0, kv_len=w * bs,
                     ),
                     iters=2,
                 )
@@ -167,8 +168,9 @@ def sweep_paged_kv_dtype(
     s, w, bs, hq, hkv, d, live = 4, 16, 16, 4, 2, 64, 8
     n = s * w + 1
     q = jnp.asarray(rng.normal(size=(s, 1, hq, d)), jnp.float32)
-    kf = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
-    vf = jnp.asarray(rng.normal(size=(n, bs, hkv, d)), jnp.float32)
+    # a stack of one layer's pools: [L=1, N, bs, Hkv, D]
+    kf = jnp.asarray(rng.normal(size=(1, n, bs, hkv, d)), jnp.float32)
+    vf = jnp.asarray(rng.normal(size=(1, n, bs, hkv, d)), jnp.float32)
     tables = jnp.arange(1, s * w + 1, dtype=jnp.int32).reshape(s, w)
     kvl = jnp.full((s,), live, jnp.int32)
     per_tok: Dict[tuple, float] = {}
@@ -191,7 +193,7 @@ def sweep_paged_kv_dtype(
             def call():
                 return ops.paged_attention(
                     q, kp, vp, tables, spec,
-                    kv_valid_len=kvl, kv_len=w * bs, kv_scales=scales,
+                    kv_valid_len=kvl, layer=0, kv_len=w * bs, kv_scales=scales,
                 )
 
             us = _t(call, iters=2)

@@ -21,11 +21,12 @@ float32 rounding.  ``fmt=None`` runs the exact float32 online softmax.
 Layout contract (mirrors ``repro.serve.paged``):
 
 * ``q``          — ``[S, Hq, D]`` one decode token per slot;
-* ``k/v_pages``  — ``[N, bs, Hkv, D]`` the flat page pool (block 0 is the
-  scratch page: free-slot writes land there, tables of retired slots point
-  there), or every layer's pools ``[L, N, bs, Hkv, D]`` with ``layer``
-  naming the one to read — the serve tick passes the whole stack so that
-  its in-place row writes never copy a layer out of it;
+* ``k/v_pages``  — ``[L, N, bs, Hkv, D]`` every layer's page pools, with
+  ``layer`` naming the one to read (a single pool is a stack of one,
+  ``pages[None]`` with ``layer=0``).  The serve tick passes the whole
+  stack so that its in-place row writes never copy a layer out of it.
+  Block 0 is the scratch page: free-slot writes land there, tables of
+  retired slots point there;
 * ``block_tables`` — ``[S, W]`` int32; logical row ``i`` of slot ``s``
   lives at ``(block_tables[s, i // bs], i % bs)``;
 * ``kv_valid``   — ``[S]`` int32 ragged valid prefix per slot.  Ring
@@ -42,7 +43,12 @@ page dtype (a one-head ``(1, bs, 1, D)`` block would cut the second-minor
 ``(bs * Hkv, D)`` rows ordered ``(position, head)``, all ``Hq`` query
 heads score against all of those rows in one MXU product, and a
 block-diagonal mask keeps each query head on the rows of its own KV head
-(``Hq // Hkv`` query heads share one — GQA).  Steps whose block lies past
+(``Hq // Hkv`` query heads share one — GQA).  The v5e compiler also takes
+the per-KV-head form (the page as ``(Hkv, bs, D)``, one product batched
+over KV heads), which does an ``Hkv``-th of the arithmetic, yet it ran
+4-6% slower at granite-8b decode shapes on a v5e (PERF.md): its
+``(group, bs)`` score tiles fill an eighth of a vreg each.  Steps whose
+block lies past
 ``kv_valid`` are predicated off with ``pl.when`` AND their index map
 clamps to the slot's last live page, so consecutive steps request the
 same block and the Pallas pipeline elides the redundant DMA — masked tail
@@ -175,17 +181,17 @@ def _kernel(
 )
 def paged_flash_attention(
     q: jax.Array,  # [S, Hq, D] one decode token per slot
-    k_pages: jax.Array,  # [N, bs, Hkv, D] flat page pool, or [L, N, ...]
+    k_pages: jax.Array,  # [L, N, bs, Hkv, D] every layer's page pool
     v_pages: jax.Array,  # same shape as k_pages
     block_tables: jax.Array,  # [S, W] int32 page ids
     kv_valid: jax.Array,  # [S] int32 valid prefix per slot
     *,
+    layer: jax.Array,  # int32: which of the L pools to read
     fmt: Optional[FixedPointFormat],  # None -> exact online softmax
     sm_scale: Optional[float] = None,
     interpret: Optional[bool] = None,  # None -> ops.platform decides
-    k_scale: Optional[jax.Array] = None,  # [N, Hkv] (or [L, N, Hkv]) f32
-    v_scale: Optional[jax.Array] = None,  # dequant scales, like k_scale
-    layer: Optional[jax.Array] = None,  # int32 index into stacked pools
+    k_scale: Optional[jax.Array] = None,  # [L, N, Hkv] f32 dequant scales
+    v_scale: Optional[jax.Array] = None,  # like k_scale
 ) -> jax.Array:
     """Gather-free paged decode attention.  Returns ``[S, Hq, D]``.
 
@@ -204,13 +210,6 @@ def paged_flash_attention(
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale, or neither")
     quantized = k_scale is not None
-    if (layer is None) != (k_pages.ndim == 4):
-        raise ValueError("pass layer with [L, N, bs, Hkv, D] pools only")
-    if layer is None:  # one layer's pool: a stack of one
-        k_pages, v_pages = k_pages[None], v_pages[None]
-        if quantized:
-            k_scale, v_scale = k_scale[None], v_scale[None]
-        layer = 0
     _, n, bs, hkv, _ = k_pages.shape
     assert hq % hkv == 0, "GQA needs Hq % Hkv == 0"
     w = block_tables.shape[1]

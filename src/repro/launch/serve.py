@@ -101,7 +101,7 @@ def run_lockstep(args, cfg, params) -> int:
 
 @contextlib.contextmanager
 def serving(cfg, cb_cfg=None, *, attn_impl=None, softmax_impl=None,
-            params=None, seed=0):
+            params=None):
     """The serve path's set-up, shared by :func:`main` and ``chip_smoke.py``.
 
     Validates the config's specs against the registry (before any
@@ -145,7 +145,7 @@ def serving(cfg, cb_cfg=None, *, attn_impl=None, softmax_impl=None,
     with ops.use(**overrides):
         if params is None:
             params = materialize(
-                build_model(cfg).param_specs(), jax.random.PRNGKey(seed))
+                build_model(cfg).param_specs(), jax.random.PRNGKey(0))
         engine = (
             ContinuousBatchingEngine(cfg, params, cb_cfg)
             if cb_cfg is not None else None

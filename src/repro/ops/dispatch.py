@@ -126,7 +126,7 @@ def attention(
 
 def paged_attention(
     q: jax.Array,  # [S, Tq, Hq, D] (decode: Tq == 1)
-    k_pages: jax.Array,  # [num_blocks, block_size, Hkv, D] (or [L, ...])
+    k_pages: jax.Array,  # [L, num_blocks, block_size, Hkv, D]
     v_pages: jax.Array,  # same shape as k_pages
     block_tables: jax.Array,  # [S, W] int32 block ids per sequence
     spec: Optional[PagedAttentionSpec] = None,
@@ -134,8 +134,8 @@ def paged_attention(
     kv_valid_len: jax.Array,  # [S] ragged valid prefix per sequence
     kv_len: Optional[int] = None,  # logical gathered length (<= W * block_size)
     scale: Optional[float] = None,
-    kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,  # ([N,H], [N,H])
-    layer: Optional[jax.Array] = None,  # int32: which of [L, ...] pools
+    layer: jax.Array,  # int32: which of the L pools to read
+    kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,  # [L, N, Hkv] each
     **overrides: Any,
 ) -> jax.Array:
     """Paged-KV decode attention: gather each sequence's blocks through its
@@ -152,9 +152,10 @@ def paged_attention(
     forbidden otherwise, so a layout/spec mismatch fails loudly here
     instead of decoding garbage.
 
-    ``layer`` reads one layer of pools stacked over layers
-    (``[L, num_blocks, ...]`` pages, ``[L, num_blocks, H]`` scales) in
-    place: the serve tick carries the whole stack.
+    Pools are stacked over layers (``[L, num_blocks, ...]`` pages,
+    ``[L, num_blocks, Hkv]`` scales) and ``layer`` names the one to read,
+    in place: the serve tick carries the whole stack.  A single pool is a
+    stack of one (``pages[None]``, ``layer=0``).
     """
     backend, spec = resolve(
         spec if spec is not None else DEFAULT_PAGED_ATTENTION, **overrides

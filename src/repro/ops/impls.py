@@ -366,12 +366,11 @@ def _make_paged_backend(impl: str, dense_fn):
         kv_len: Optional[int] = None,
         scale: Optional[float] = None,
         kv_scales: Optional[tuple] = None,
-        layer: Optional[jax.Array] = None,
+        layer: jax.Array,
     ) -> jax.Array:
-        if layer is not None:
-            k_pages, v_pages = k_pages[layer], v_pages[layer]
-            if kv_scales is not None:
-                kv_scales = tuple(sc[layer] for sc in kv_scales)
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if kv_scales is not None:
+            kv_scales = tuple(sc[layer] for sc in kv_scales)
         kd, vd = _gather_pages(k_pages, v_pages, block_tables, kv_len, kv_scales)
         return dense_fn(
             _paged_dense_spec(spec, impl),
@@ -429,7 +428,7 @@ def _paged_pallas_paged(
     kv_len: Optional[int] = None,
     scale: Optional[float] = None,
     kv_scales: Optional[tuple] = None,
-    layer: Optional[jax.Array] = None,
+    layer: jax.Array,
 ) -> jax.Array:
     """Gather-free decode: the kernel walks the block table in place."""
     if q.shape[1] != 1:

@@ -46,23 +46,55 @@ def _phases(smoke):
     )
 
 
-@pytest.mark.parametrize("which", [0, 1])
-def test_phase_serves_budgets_and_matches_references(smoke, model_params, which):
+@pytest.fixture(scope="module")
+def served(smoke, model_params):
+    """Each rehearsal phase served once: its report and logit readings."""
     cfg, params = model_params
-    phase = _phases(smoke)[which]
-    rep = smoke.run_phase(cfg, params, phase, pool_blocks=64, slots=2)
+    out = []
+    for phase in _phases(smoke):
+        rep = smoke.run_phase(cfg, params, phase, pool_blocks=64, slots=2)
+        out.append((phase, rep, smoke.check_logits(cfg, params, rep["tracked"])))
+    return out
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_phase_serves_budgets_and_matches_references(smoke, served, which):
+    phase, rep, logits = served[which]
     assert rep["tokens_served"] == sum(phase.gens)
-    assert rep["kernel_max_err"] <= smoke.KERNEL_TOL
+    assert rep["kernel"]["error"] <= smoke.KERNEL_TOL
     if phase.prefix_cache:
         assert rep["prefix_tokens_saved"] > 0
     tracked = rep["tracked"]
     assert [len(t["logits"]) for t in tracked] == [
         phase.gens[i] - 1 for i in phase.track]
-    assert smoke.check_logits(cfg, params, tracked) <= smoke.LOGITS_TOL
+    assert logits["error"] <= smoke.LOGITS_TOL
     # the kernel really was the one dispatched, resolved for this platform
     disp = smoke.pallas_dispatches()
     assert any(d["impl"] == "pallas_paged" for d in disp)
     assert all(d["interpret"] for d in disp)  # CPU: interpret mode
+
+
+@pytest.mark.parametrize("which", [0, 1])
+@pytest.mark.parametrize("fault", ["page", "head"])
+def test_kernel_check_sees_planted_fault(smoke, served, which, fault):
+    """A page of another slot, or a query head reading its neighbour's KV
+    head, must move the kernel's output past the check's limit."""
+    assert served[which][1]["kernel"][fault] > smoke.KERNEL_TOL
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_logit_check_sees_float8_compute(smoke, served, which):
+    """Logits computed in float8, below the config's bfloat16, must read
+    past the logit check's limit."""
+    assert served[which][2]["float8"] > smoke.LOGITS_TOL
+
+
+def test_separation_refuses_a_blind_check(smoke):
+    smoke.require_separation("x", {"error": 1e-3, "page": 0.5}, 1e-2)
+    with pytest.raises(AssertionError, match="error"):
+        smoke.require_separation("x", {"error": 2e-2, "page": 0.5}, 1e-2)
+    with pytest.raises(AssertionError, match="cannot see"):
+        smoke.require_separation("x", {"error": 1e-3, "page": 5e-3}, 1e-2)
 
 
 def test_logit_check_fails_on_wrong_logits(smoke, model_params):
@@ -71,7 +103,7 @@ def test_logit_check_fails_on_wrong_logits(smoke, model_params):
     toks = rng.integers(0, cfg.vocab_size, 12).astype(np.int32)
     wrong = {i: rng.normal(size=cfg.vocab_size).astype(np.float32)
              for i in (1, 2)}
-    with pytest.raises(AssertionError, match="relative error"):
+    with pytest.raises(AssertionError, match="error"):
         smoke.check_logits(cfg, params, [
             {"tokens": toks, "prompt_len": 9, "logits": wrong}])
 

@@ -134,7 +134,8 @@ def _quantized_operands(kv_dtype, s=3, w=4, bs=8, hq=4, hkv=2, d=16,
     perm = RNG.permutation(np.arange(1, n))
     tables = jnp.asarray(perm[: s * w].reshape(s, w), jnp.int32)
     kvl = jnp.asarray(lens, jnp.int32)
-    return q, kp, vp, (ks, vs), tables, kvl
+    # a stack of one layer's pools: [L=1, N, ...]
+    return q, kp[None], vp[None], (ks[None], vs[None]), tables, kvl
 
 
 @pytest.mark.parametrize("kv_dtype", QUANT_DTYPES)
@@ -148,9 +149,9 @@ def test_kernel_parity_vs_dequant_oracle(kv_dtype, kind):
             softmax=ops.SoftmaxSpec(kind=kind),
         )
     ref = ops.paged_attention(q, kp, vp, tables, mk("xla"),
-                              kv_valid_len=kvl, kv_scales=scales)
+                              kv_valid_len=kvl, layer=0, kv_scales=scales)
     out = ops.paged_attention(q, kp, vp, tables, mk("pallas_paged"),
-                              kv_valid_len=kvl, kv_scales=scales)
+                              kv_valid_len=kvl, layer=0, kv_scales=scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
@@ -161,9 +162,9 @@ def test_kernel_parity_ring_clamp_quantized():
         return ops.PagedAttentionSpec(impl=impl, block_size=8,
                                       kv_dtype="int8")
     ref = ops.paged_attention(q, kp, vp, tables, mk("reference"),
-                              kv_valid_len=kvl, kv_len=16, kv_scales=scales)
+                              kv_valid_len=kvl, layer=0, kv_len=16, kv_scales=scales)
     out = ops.paged_attention(q, kp, vp, tables, mk("pallas_paged"),
-                              kv_valid_len=kvl, kv_len=16, kv_scales=scales)
+                              kv_valid_len=kvl, layer=0, kv_len=16, kv_scales=scales)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
@@ -173,11 +174,11 @@ def test_int8_output_close_to_fp32_reference():
     q, kp, vp, scales, tables, kvl = _quantized_operands("int8")
     spec8 = ops.PagedAttentionSpec(impl="xla", block_size=8, kv_dtype="int8")
     out8 = ops.paged_attention(q, kp, vp, tables, spec8,
-                               kv_valid_len=kvl, kv_scales=scales)
-    kf = kvquant.decode(kp, scales[0][:, None, :, None])
-    vf = kvquant.decode(vp, scales[1][:, None, :, None])
+                               kv_valid_len=kvl, layer=0, kv_scales=scales)
+    kf = kvquant.decode(kp, scales[0][..., None, :, None])
+    vf = kvquant.decode(vp, scales[1][..., None, :, None])
     spec32 = ops.PagedAttentionSpec(impl="xla", block_size=8)
-    out32 = ops.paged_attention(q, kf, vf, tables, spec32, kv_valid_len=kvl)
+    out32 = ops.paged_attention(q, kf, vf, tables, spec32, kv_valid_len=kvl, layer=0)
     # identical codes: dequantized-operand attention == quantized attention
     np.testing.assert_allclose(np.asarray(out8), np.asarray(out32), atol=3e-6)
 
@@ -186,12 +187,12 @@ def test_dispatch_requires_scales_iff_quantized():
     q, kp, vp, scales, tables, kvl = _quantized_operands("int8")
     spec = ops.PagedAttentionSpec(impl="xla", block_size=8, kv_dtype="int8")
     with pytest.raises(ops.OpDispatchError, match="kv_scales"):
-        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
+        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0)
     fp32 = ops.PagedAttentionSpec(impl="xla", block_size=8)
     with pytest.raises(ops.OpDispatchError, match="kv_scales"):
         ops.paged_attention(
             q, kp.astype(jnp.float32), vp.astype(jnp.float32), tables, fp32,
-            kv_valid_len=kvl, kv_scales=scales,
+            kv_valid_len=kvl, layer=0, kv_scales=scales,
         )
 
 
@@ -202,13 +203,13 @@ def test_quantized_kernel_never_materializes_gathered_window(kv_dtype):
     one page at a time in VMEM."""
     q, kp, vp, scales, tables, kvl = _quantized_operands(kv_dtype)
     s, w = tables.shape
-    _, bs, hkv, d = kp.shape
+    _, _, bs, hkv, d = kp.shape
     spec = ops.PagedAttentionSpec(
         impl="pallas_paged", block_size=bs, kv_dtype=kv_dtype)
 
     def call(q, kp, vp, ks, vs, tables, kvl):
         return ops.paged_attention(q, kp, vp, tables, spec,
-                                   kv_valid_len=kvl, kv_scales=(ks, vs))
+                                   kv_valid_len=kvl, layer=0, kv_scales=(ks, vs))
 
     assert not ops.materializes(
         call, (s, w * bs, hkv, d), q, kp, vp, *scales, tables, kvl)

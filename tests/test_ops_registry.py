@@ -303,8 +303,9 @@ PAGED_IMPLS = [b.impl for b in ops.backends("paged_attention")]
 def _paged_operands(s=3, w=3, bs=4, hq=4, hkv=2, d=16):
     n = s * w + 1  # block 0 reserved as scratch
     q = jnp.asarray(RNG.normal(size=(s, 1, hq, d)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(n, bs, hkv, d)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(n, bs, hkv, d)), jnp.float32)
+    # a stack of one layer's pools: [L=1, N, bs, Hkv, D]
+    kp = jnp.asarray(RNG.normal(size=(1, n, bs, hkv, d)), jnp.float32)
+    vp = jnp.asarray(RNG.normal(size=(1, n, bs, hkv, d)), jnp.float32)
     tables = jnp.asarray(
         [[i * w + j + 1 for j in range(w)] for i in range(s)], jnp.int32
     )
@@ -322,9 +323,11 @@ def test_paged_attention_backend_parity(impl):
     q, kp, vp, tables, kvl = _paged_operands()
     spec = ops.PagedAttentionSpec(impl=impl, block_size=4)
     ref = ops.paged_attention(
-        q, kp, vp, tables, spec, kv_valid_len=kvl, kv_len=10, impl="reference"
+        q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0, kv_len=10, impl="reference"
     )
-    out = ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, kv_len=10)
+    out = ops.paged_attention(
+        q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0, kv_len=10
+    )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
 
@@ -334,10 +337,10 @@ def test_paged_attention_matches_dense_gather(impl):
     must agree with dense attention over the manually flattened blocks."""
     q, kp, vp, tables, kvl = _paged_operands()
     s, w = tables.shape
-    bs = kp.shape[1]
+    bs = kp.shape[2]
     flat = np.asarray(tables).reshape(-1)
-    kd = jnp.asarray(np.asarray(kp)[flat].reshape(s, w * bs, *kp.shape[2:])[:, :10])
-    vd = jnp.asarray(np.asarray(vp)[flat].reshape(s, w * bs, *vp.shape[2:])[:, :10])
+    kd = jnp.asarray(np.asarray(kp[0])[flat].reshape(s, w * bs, *kp.shape[3:])[:, :10])
+    vd = jnp.asarray(np.asarray(vp[0])[flat].reshape(s, w * bs, *vp.shape[3:])[:, :10])
     dense = ops.attention(
         q,
         kd,
@@ -352,6 +355,7 @@ def test_paged_attention_matches_dense_gather(impl):
         tables,
         ops.PagedAttentionSpec(impl=impl, block_size=4),
         kv_valid_len=kvl,
+        layer=0,
         kv_len=10,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=3e-6)
@@ -360,7 +364,7 @@ def test_paged_attention_matches_dense_gather(impl):
 def test_paged_attention_use_override():
     q, kp, vp, tables, kvl = _paged_operands()
     ref = ops.paged_attention(
-        q, kp, vp, tables, kv_valid_len=kvl, kv_len=10, impl="reference"
+        q, kp, vp, tables, kv_valid_len=kvl, layer=0, kv_len=10, impl="reference"
     )
     with ops.use(paged_attention="reference"):
         out = ops.paged_attention(
@@ -370,6 +374,7 @@ def test_paged_attention_use_override():
             tables,
             ops.PagedAttentionSpec(impl="xla"),
             kv_valid_len=kvl,
+        layer=0,
             kv_len=10,
         )
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -381,7 +386,7 @@ def test_paged_attention_pallas_capability():
         impl="pallas", softmax=ops.SoftmaxSpec(kind="star_ste")
     )
     with pytest.raises(ops.CapabilityError, match="pallas"):
-        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
+        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0)
 
 
 def test_paged_spec_validation_and_json():

@@ -41,8 +41,9 @@ MAX_LEN = 40
 def _operands(s=3, w=4, bs=8, hq=4, hkv=2, d=16, lens=(6, 25, 0)):
     n = s * w + 1  # block 0 reserved as scratch
     q = jnp.asarray(RNG.normal(size=(s, 1, hq, d)), jnp.float32)
-    kp = jnp.asarray(RNG.normal(size=(n, bs, hkv, d)), jnp.float32)
-    vp = jnp.asarray(RNG.normal(size=(n, bs, hkv, d)), jnp.float32)
+    # a stack of one layer's pools: [L=1, N, bs, Hkv, D]
+    kp = jnp.asarray(RNG.normal(size=(1, n, bs, hkv, d)), jnp.float32)
+    vp = jnp.asarray(RNG.normal(size=(1, n, bs, hkv, d)), jnp.float32)
     # shuffled non-contiguous tables: the kernel must follow the table,
     # not the pool order
     perm = RNG.permutation(np.arange(1, n))
@@ -66,10 +67,10 @@ def _spec(impl, kind, bs):
 def test_parity_ragged_vs_gather_reference(bs, kind):
     q, kp, vp, tables, kvl = _operands(bs=bs, lens=(6, 25, 2))
     ref = ops.paged_attention(
-        q, kp, vp, tables, _spec("reference", kind, bs), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("reference", kind, bs), kv_valid_len=kvl, layer=0
     )
     out = ops.paged_attention(
-        q, kp, vp, tables, _spec("pallas_paged", kind, bs), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("pallas_paged", kind, bs), kv_valid_len=kvl, layer=0
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
@@ -82,7 +83,7 @@ def test_empty_slot_emits_zeros(kind):
     the parity sweep never includes a zero-length slot)."""
     q, kp, vp, tables, kvl = _operands(lens=(6, 25, 0))
     out = ops.paged_attention(
-        q, kp, vp, tables, _spec("pallas_paged", kind, 8), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("pallas_paged", kind, 8), kv_valid_len=kvl, layer=0
     )
     assert np.all(np.asarray(out)[2] == 0.0)
     assert np.all(np.isfinite(np.asarray(out)))
@@ -93,10 +94,10 @@ def test_parity_block_boundary_lengths(lens):
     """Valid lengths on and just past block edges (the mask/clamp seams)."""
     q, kp, vp, tables, kvl = _operands(bs=8, lens=lens)
     ref = ops.paged_attention(
-        q, kp, vp, tables, _spec("reference", "star", 8), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("reference", "star", 8), kv_valid_len=kvl, layer=0
     )
     out = ops.paged_attention(
-        q, kp, vp, tables, _spec("pallas_paged", "star", 8), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("pallas_paged", "star", 8), kv_valid_len=kvl, layer=0
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
@@ -107,11 +108,11 @@ def test_parity_ring_clamp_kv_len():
     q, kp, vp, tables, kvl = _operands(bs=8, w=4, lens=(30, 32, 12))
     ref = ops.paged_attention(
         q, kp, vp, tables, _spec("reference", "star", 8),
-        kv_valid_len=kvl, kv_len=16,
+        kv_valid_len=kvl, layer=0, kv_len=16,
     )
     out = ops.paged_attention(
         q, kp, vp, tables, _spec("pallas_paged", "star", 8),
-        kv_valid_len=kvl, kv_len=16,
+        kv_valid_len=kvl, layer=0, kv_len=16,
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
@@ -120,10 +121,10 @@ def test_parity_ring_clamp_kv_len():
 def test_parity_gqa_ratios(hq, hkv):
     q, kp, vp, tables, kvl = _operands(hq=hq, hkv=hkv, lens=(6, 25, 11))
     ref = ops.paged_attention(
-        q, kp, vp, tables, _spec("reference", "exact", 8), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("reference", "exact", 8), kv_valid_len=kvl, layer=0
     )
     out = ops.paged_attention(
-        q, kp, vp, tables, _spec("pallas_paged", "exact", 8), kv_valid_len=kvl
+        q, kp, vp, tables, _spec("pallas_paged", "exact", 8), kv_valid_len=kvl, layer=0
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-6)
 
@@ -132,14 +133,15 @@ def test_parity_gqa_ratios(hq, hkv):
 @pytest.mark.parametrize("layer", [0, 2])
 def test_stacked_pools_read_the_named_layer(impl, layer):
     """``layer`` reads one slab of ``[L, N, bs, Hkv, D]`` pools (the serve
-    tick's layout) exactly as the same call on that slab alone; the other
+    tick's layout) exactly as the same call on that slab as a stack of
+    one; the other
     layers hold different values, so a wrong slab shows."""
     q, kp, vp, tables, kvl = _operands(lens=(6, 25, 11))
-    kst = jnp.stack([kp, -kp, 2 * kp])
-    vst = jnp.stack([vp, vp + 1.0, -vp])
+    kst = jnp.concatenate([kp, -kp, 2 * kp])
+    vst = jnp.concatenate([vp, vp + 1.0, -vp])
     spec = _spec(impl, "star", 8)
-    want = ops.paged_attention(q, kst[layer], vst[layer], tables, spec,
-                               kv_valid_len=kvl)
+    want = ops.paged_attention(q, kst[layer][None], vst[layer][None], tables,
+                               spec, kv_valid_len=kvl, layer=0)
     got = ops.paged_attention(q, kst, vst, tables, spec, kv_valid_len=kvl,
                               layer=jnp.int32(layer))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -149,13 +151,14 @@ def test_kernel_rejects_bad_gqa_and_multitoken_queries():
     q, kp, vp, tables, kvl = _operands()
     with pytest.raises(AssertionError, match="GQA"):
         paged_flash_attention(
-            q[:, 0, :3], kp, vp, tables, kvl, fmt=None, interpret=True
+            q[:, 0, :3], kp, vp, tables, kvl, layer=0, fmt=None,
+            interpret=True,
         )
     q2 = jnp.concatenate([q, q], axis=1)  # Tq = 2
     with pytest.raises(ops.CapabilityError, match="decode kernel"):
         ops.paged_attention(
             q2, kp, vp, tables, _spec("pallas_paged", "star", 8),
-            kv_valid_len=kvl,
+            kv_valid_len=kvl, layer=0,
         )
 
 
@@ -166,11 +169,11 @@ def test_kernel_rejects_bad_gqa_and_multitoken_queries():
 def _materializes_gathered_operand(impl) -> bool:
     q, kp, vp, tables, kvl = _operands()
     s, w = tables.shape
-    _, bs, hkv, d = kp.shape
+    _, _, bs, hkv, d = kp.shape
     spec = _spec(impl, "star", bs)
 
     def call(q, kp, vp, tables, kvl):
-        return ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
+        return ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0)
 
     return ops.materializes(call, (s, w * bs, hkv, d), q, kp, vp, tables, kvl)
 
@@ -209,7 +212,7 @@ def test_fault_capability_refused():
         impl="pallas_paged", softmax=ops.SoftmaxSpec(kind="star", fault=fault)
     )
     with pytest.raises(ops.CapabilityError, match="pallas_paged"):
-        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
+        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0)
 
 
 def test_star_ste_kind_refused():
@@ -218,7 +221,7 @@ def test_star_ste_kind_refused():
         impl="pallas_paged", softmax=ops.SoftmaxSpec(kind="star_ste")
     )
     with pytest.raises(ops.CapabilityError, match="pallas_paged"):
-        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl)
+        ops.paged_attention(q, kp, vp, tables, spec, kv_valid_len=kvl, layer=0)
 
 
 # ---------------------------------------------------------------------------
