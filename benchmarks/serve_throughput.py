@@ -158,10 +158,6 @@ def run_continuous(cfg, params, trace, prompts, slots, max_len, *,
         out["total_blocks"] = st["total_blocks"]
         out["preemptions"] = st["preemptions"]
         out["kv_block_size"] = kv_block_size
-        # counted pool-read traffic for the resolved paged backend
-        # (DESIGN.md §11): the gather adapters pay the full table window,
-        # pallas_paged pays live pages only
-        out["gather_bytes_per_token"] = st["gather_bytes_per_token"]
         out["prefix"] = st.get("prefix")
         # quantized-layout accounting (DESIGN.md §13): amortized storage
         # cost of one cached token, scale pages included
@@ -211,9 +207,6 @@ def main(n_requests: int = 12, slots: int = 4, kv_block_size: int = 16,
           f"toks_per_s={pg['tokens'] / pg['wall']:.1f} "
           f"peak_blocks={pg['peak_used_blocks']}/{pg['total_blocks']} "
           f"preemptions={pg['preemptions']}")
-    print(f"serve_paged_gather_bytes_per_token,"
-          f"{pg['gather_bytes_per_token']:.0f},"
-          f"counted_pool_read_traffic source=kv_stats")
 
     print(f"serve_continuous_step_speedup,{lk['steps'] / cb['steps']:.2f}x,"
           f"device_decode_work requests={n_requests} slots={slots}")
@@ -276,7 +269,6 @@ def main(n_requests: int = 12, slots: int = 4, kv_block_size: int = 16,
             "kv_bytes_per_token": r["kv_bytes_per_token"],
             "peak_kv_bytes": r["peak_kv_bytes"],
             "peak_used_blocks": r["peak_used_blocks"],
-            "gather_bytes_per_token": r["gather_bytes_per_token"],
             "makespan": r["makespan"],
         }
         print(f"serve_paged_kv_bytes_per_token[{kvd}],"

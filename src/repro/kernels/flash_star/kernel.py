@@ -229,5 +229,6 @@ def flash_star_attention(
         out_shape=jax.ShapeDtypeStruct((batch, hq, tq + pad_q, d), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="flash_star",
     )(info, q, k, v)
     return out[:, :, :tq]

@@ -195,6 +195,7 @@ def star_softmax_pallas(
             in_specs=[block],
             out_specs=block,
             interpret=interpret,
+            name="star_softmax",
         )(x2)
         return out[:rows].reshape(orig_shape)
 
@@ -218,6 +219,7 @@ def star_softmax_pallas(
         in_specs=[block, table_spec, table_spec, table_spec],
         out_specs=block,
         interpret=interpret,
+        name="star_softmax",
     )(
         x2,
         lut.reshape(nl, 1),

@@ -84,26 +84,30 @@ class DecoderLM:
         moe_capacity: Optional[int] = None,
     ) -> Tuple[jax.Array, Optional[Params], Tuple[jax.Array, jax.Array], Optional[jax.Array]]:
         cfg = self.cfg
-        a, new_cache, kv = L.attention_block(
-            bp["attn"], L.rmsnorm(bp["ln1"], h, cfg.norm_eps), cfg,
-            causal=True, positions=positions,
-            sliding_window=cfg.sliding_window, cache=cache,
-            kv_valid_len=kv_valid_len, paged_cache_t=paged_cache_t,
-        )
-        h = h + L.attention_out(bp["attn"], a, cfg)
-        hn = L.rmsnorm(bp["ln2"], h, cfg.norm_eps)
+        # named scopes tag the compiled ops (HLO op_name metadata), so a
+        # device trace's ops map back to the block's parts
+        with jax.named_scope("attention"):
+            a, new_cache, kv = L.attention_block(
+                bp["attn"], L.rmsnorm(bp["ln1"], h, cfg.norm_eps), cfg,
+                causal=True, positions=positions,
+                sliding_window=cfg.sliding_window, cache=cache,
+                kv_valid_len=kv_valid_len, paged_cache_t=paged_cache_t,
+            )
+            h = h + L.attention_out(bp["attn"], a, cfg)
         moe_state = None
-        if cfg.family == "moe":
-            prior = cache.get("moe") if cache is not None else None
-            if prior is not None or moe_capacity is not None:
-                # chunked prefill: global expert-queue positions + the
-                # full-sequence capacity keep dropping chunk-invariant
-                y, moe_state = L.moe(bp["moe"], hn, cfg, state=prior, capacity=moe_capacity)
-                h = h + y
+        with jax.named_scope("ffn"):
+            hn = L.rmsnorm(bp["ln2"], h, cfg.norm_eps)
+            if cfg.family == "moe":
+                prior = cache.get("moe") if cache is not None else None
+                if prior is not None or moe_capacity is not None:
+                    # chunked prefill: global expert-queue positions + the
+                    # full-sequence capacity keep dropping chunk-invariant
+                    y, moe_state = L.moe(bp["moe"], hn, cfg, state=prior, capacity=moe_capacity)
+                    h = h + y
+                else:
+                    h = h + L.moe(bp["moe"], hn, cfg)
             else:
-                h = h + L.moe(bp["moe"], hn, cfg)
-        else:
-            h = h + L.mlp(bp["mlp"], hn, cfg)
+                h = h + L.mlp(bp["mlp"], hn, cfg)
         return h, new_cache, kv, moe_state
 
     def _run_blocks(
@@ -461,8 +465,9 @@ class DecoderLM:
             {"p": params["blocks"],
              "layer": jnp.arange(cfg.num_layers, dtype=jnp.int32)},
         )
-        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-        logits = L.unembed(params["unembed"], h, cfg, params["embed"])
+        with jax.named_scope("unembed"):
+            h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+            logits = L.unembed(params["unembed"], h, cfg, params["embed"])
         new_cache = {
             "layers": {name: new_layer_caches[name] for name in kv_leaves},
             "len": cache["len"] + 1,
@@ -681,8 +686,9 @@ class DecoderLM:
             return out, {"k": new_c["k"], "v": new_c["v"]}
 
         h, new_layer_caches = L.scan_blocks(body, x, {"p": params["blocks"], "c": cache["layers"]})
-        h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
-        logits = L.unembed(params["unembed"], h, cfg, params["embed"])
+        with jax.named_scope("unembed"):
+            h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
+            logits = L.unembed(params["unembed"], h, cfg, params["embed"])
         new_cache = {
             "layers": {"k": new_layer_caches["k"], "v": new_layer_caches["v"]},
             "len": cache["len"] + 1,

@@ -3,8 +3,10 @@
 Three pillars, pure stdlib (never imports jax, so the host-side
 scheduler/allocator layers can depend on it freely):
 
-* **Tracing** (:mod:`repro.obs.trace`): span context managers and
-  explicit begin/end events into a bounded ring buffer; a process-global
+* **Tracing** (:mod:`repro.obs.trace`): span context managers, instants,
+  counters and per-request async tracks into a bounded ring buffer; an
+  optional annotation sink that enters each span in a profiler's trace
+  too (the serve engine supplies ``jax.profiler``'s); a process-global
   no-op tracer when disabled (one method call, zero recording on the hot
   path); Chrome trace-event JSON export viewable at
   https://ui.perfetto.dev.
@@ -12,8 +14,9 @@ scheduler/allocator layers can depend on it freely):
   ``Histogram`` (log-spaced fixed buckets, exact sum/min/max) behind a
   labeled :class:`MetricsRegistry` with ``snapshot() -> dict``.
 * **Instrumentation** wired through the stack: serve engine request
-  lifecycle (TTFT / ITL / queue-wait histograms, prefill/decode spans,
-  per-request async tracks), scheduler + block-pool gauges and counters,
+  lifecycle (TTFT / ITL / queue-wait histograms, one span per tick phase,
+  per-request async tracks, compile counters per phase), scheduler +
+  block-pool gauges and counters,
   ``ops.dispatch`` per-(op, impl) call counters, and accuracy-guard trip
   events.
 

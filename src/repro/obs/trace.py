@@ -10,7 +10,10 @@ implementations share one duck-typed surface:
   process never grows unbounded) and exports them as Chrome trace-event
   JSON (``chrome://tracing`` / https://ui.perfetto.dev).  The time source
   is injectable (``clock=``, a zero-arg callable returning seconds) so
-  tests assert exact timestamps.
+  tests assert exact timestamps.  An optional *annotation sink*
+  (``annotate=``, e.g. ``jax.profiler.TraceAnnotation``) is entered for
+  every span's duration as well, so the spans also land in a profiler's
+  own trace, on the clock of its device timeline.
 * :class:`NullTracer` — the process-global default.  Every method is a
   no-op returning shared singletons: ``span()`` hands back one reusable
   context manager, so a disabled trace point costs one attribute lookup
@@ -24,9 +27,8 @@ engines capture :func:`get_tracer` at construction.
 
 Event vocabulary (Chrome trace-event ``ph`` codes):
 
-* ``span(name, **args)`` — a complete ``"X"`` event (begin time + dur).
-* ``begin(name)`` / ``end(name)`` — explicit ``"B"`` / ``"E"`` pairs for
-  regions that cannot be a ``with`` block.
+* ``span(name, **args)`` — a complete ``"X"`` event (begin time + dur),
+  and the sink's annotation around it with the scalar ``args``.
 * ``async_begin/async_end(name, id)`` — ``"b"`` / ``"e"`` events keyed by
   ``id``: one open span per *request* across many ticks (each request
   gets its own track in Perfetto).
@@ -42,10 +44,15 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import numbers
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, ContextManager, Dict, List, Optional
+
+# ``annotate(name, **scalar_args)`` -> a context manager entered for a span's
+# duration (``jax.profiler.TraceAnnotation`` has this shape)
+Annotate = Callable[..., ContextManager[Any]]
 
 
 @dataclasses.dataclass
@@ -53,7 +60,7 @@ class TraceEvent:
     """One trace-event row (field names mirror the Chrome JSON keys)."""
 
     name: str
-    ph: str  # B | E | X | i | b | e | C
+    ph: str  # X | i | b | e | C
     ts: float  # microseconds since the tracer's epoch
     dur: Optional[float] = None  # X only: span duration in microseconds
     tid: int = 0
@@ -80,22 +87,35 @@ class TraceEvent:
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, inside
+    the tracer's annotation (when it has a sink) for the same duration."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict[str, Any]):
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            # profilers take scalar stats; lists (e.g. of uids) stay in
+            # the ring buffer's copy only
+            self._ann = annotate(self._name, **{
+                k: v for k, v in self._args.items()
+                if isinstance(v, (numbers.Number, str))})
+            self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = self._tracer._now_us()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         self._tracer._append(TraceEvent(
             self._name, "X", self._t0, dur=t1 - self._t0,
             tid=threading.get_ident() & 0xFFFFFF, cat=self._cat,
@@ -111,7 +131,9 @@ class Tracer:
     in ``dropped``); ``clock`` is a zero-arg callable returning seconds —
     ``time.perf_counter`` by default, a fake clock in tests.  Timestamps
     are microseconds relative to the tracer's construction, which is what
-    the Chrome trace-event format expects.
+    the Chrome trace-event format expects.  ``annotate`` (settable later
+    through the attribute) is the span sink described in the module
+    docstring; None records to the ring buffer alone.
     """
 
     enabled = True
@@ -121,10 +143,12 @@ class Tracer:
         *,
         capacity: int = 65536,
         clock: Callable[[], float] = time.perf_counter,
+        annotate: Optional[Annotate] = None,
     ):
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
         self.capacity = capacity
+        self.annotate = annotate
         self._clock = clock
         self._epoch = clock()
         self._buf: deque[TraceEvent] = deque(maxlen=capacity)
@@ -142,18 +166,6 @@ class Tracer:
 
     def span(self, name: str, *, cat: str = "repro", **args: Any) -> _Span:
         return _Span(self, name, cat, args)
-
-    def begin(self, name: str, *, cat: str = "repro", **args: Any) -> None:
-        self._append(TraceEvent(
-            name, "B", self._now_us(),
-            tid=threading.get_ident() & 0xFFFFFF, cat=cat, args=args or None,
-        ))
-
-    def end(self, name: str, *, cat: str = "repro") -> None:
-        self._append(TraceEvent(
-            name, "E", self._now_us(),
-            tid=threading.get_ident() & 0xFFFFFF, cat=cat,
-        ))
 
     def async_begin(self, name: str, id: int, *, cat: str = "request",
                     **args: Any) -> None:
@@ -224,17 +236,12 @@ class NullTracer:
     """
 
     enabled = False
+    annotate = None
     events: List[TraceEvent] = []
     dropped = 0
 
     def span(self, name: str, *, cat: str = "repro", **args: Any) -> _NullSpan:
         return _NULL_SPAN
-
-    def begin(self, name: str, *, cat: str = "repro", **args: Any) -> None:
-        pass
-
-    def end(self, name: str, *, cat: str = "repro") -> None:
-        pass
 
     def async_begin(self, name: str, id: int, *, cat: str = "request",
                     **args: Any) -> None:
@@ -278,9 +285,10 @@ def enable_tracing(
     *,
     capacity: int = 65536,
     clock: Callable[[], float] = time.perf_counter,
+    annotate: Optional[Annotate] = None,
 ) -> Tracer:
     """Install (and return) a fresh recording tracer as the global one."""
-    tracer = Tracer(capacity=capacity, clock=clock)
+    tracer = Tracer(capacity=capacity, clock=clock, annotate=annotate)
     set_tracer(tracer)
     return tracer
 

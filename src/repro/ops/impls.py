@@ -494,7 +494,7 @@ def paged_gather_bytes(
     pages: ``sum(ceil(live / bs)) * bs`` rows (free slots still touch the
     one clamped page, matching the kernel's DMA-elision behaviour).  This
     is the interpret-normalized traffic model behind
-    ``gather_bytes_per_token`` in ``kv_stats``/benchmarks — a counted
+    ``benchmarks/kernel_bench.py``'s ``gather_bytes`` — a counted
     quantity, not a measurement.
 
     ``dtype_bytes`` is the page-pool leaf itemsize (1 for int8/fp8 codes);

@@ -52,6 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -68,6 +69,44 @@ from repro.serve.paged import SCRATCH_BLOCK, BlockPool, PrefixCache, bucket_bloc
 from repro.serve.scheduler import Request, Slot, SlotScheduler
 
 PyTree = Any
+
+
+def profiler_annotation(name: str, **args: Any):
+    """The tracer's span sink onto ``jax.profiler`` (DESIGN.md §10): a span
+    with a ``step_num`` (the tick's ``serve.step``) is a step annotation,
+    every other span a plain one, with its scalar args as event stats."""
+    if "step_num" in args:
+        return jax.profiler.StepTraceAnnotation(name, **args)
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+# Compile accounting (DESIGN.md §10): one process-wide ``jax.monitoring``
+# listener charges every trace, lowering and backend compile (a persistent
+# cache load is timed as a backend compile) to the engine that last
+# entered ``step()`` or was built, under that engine's open phase.  A weak
+# reference: the listener never keeps an engine (and its KV pool) alive.
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_charged: Optional["weakref.ReferenceType[ContinuousBatchingEngine]"] = None
+_listening = False
+
+
+def _on_compile(event: str, duration: float, **_: Any) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    eng = _charged() if _charged is not None else None
+    if stage is not None and eng is not None:
+        eng._count_compile(stage, duration)
+
+
+def _charge_compiles_to(ref: "weakref.ReferenceType[ContinuousBatchingEngine]") -> None:
+    global _charged, _listening
+    _charged = ref
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _listening = True
 
 
 @dataclasses.dataclass
@@ -247,22 +286,32 @@ class ContinuousBatchingEngine:
             "serve.queue_wait_s", "pending-queue wait per admission stint")
         self._g_queue = reg.gauge("serve.queue.depth")
         self._g_active = reg.gauge("serve.slots.active")
-        # Transfer / retrace accounting (DESIGN.md §11): counted bytes the
-        # tick moves across the host-device boundary, the counted KV bytes
-        # decode reads out of the page pool (traffic model, not a
-        # measurement), and the pooled jit-cache entry count.
+        # Transfer accounting (DESIGN.md §11): counted bytes the tick
+        # moves across the host-device boundary.
         self._m_h2d = reg.counter(
             "serve.bytes.h2d", "host->device bytes per tick (token inputs, "
             "dirty table rows, sampling uid/step vectors)")
         self._m_d2h = reg.counter(
             "serve.bytes.d2h", "device->host bytes per tick (the sampled "
             "token vector; admission adds one token per prefill)")
-        self._m_gather = reg.counter(
-            "kv.gather.bytes", "counted K+V bytes decode reads from the KV "
-            "pool (ops.paged_gather_bytes traffic model)")
-        self._g_jit = reg.gauge(
-            "serve.jit.entries", "pooled jit-cache entries across the "
-            "engine's compiled callables")
+        # Compile accounting (DESIGN.md §10), by the phase that was open:
+        # admit | prefill | prefill_chunk | prefill_finish | blocks |
+        # decode inside step() (``step`` between them), ``other`` outside.
+        self._m_lowerings = reg.counter(
+            "serve.compile.lowerings", "programs lowered to MLIR, by phase")
+        self._m_compile_s = reg.counter(
+            "serve.compile.seconds", "seconds of jaxpr trace, MLIR lowering "
+            "and backend compile or cache load, by phase")
+        self._m_chunks = reg.counter(
+            "serve.prefill.chunks", "eager prefill chunk calls")
+        self.phase = "other"
+        self.steps = 0  # step() calls: the profiler's step number
+        self._ref = weakref.ref(self)
+        _charge_compiles_to(self._ref)
+        if self.tracer.enabled and self.tracer.annotate is None:
+            # a recording tracer also enters its spans in the profiler's
+            # trace, on the device timeline's clock
+            self.tracer.annotate = profiler_annotation
         self.model = build_model(model_cfg)
         if not isinstance(self.model, DecoderLM):
             raise ValueError(
@@ -418,17 +467,18 @@ class ContinuousBatchingEngine:
             else:
                 logits, pool = model.decode_step(params, pool, inputs)
             last = logits[:, -1]  # [S, V]
-            if serve_cfg.temperature <= 0.0:
-                sampled = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            else:
-                keys = jax.vmap(
-                    lambda u, i: jax.random.fold_in(
-                        jax.random.fold_in(base_key, u), i
-                    )
-                )(uids, steps)
-                sampled = jax.vmap(
-                    lambda lg, k: sample_token(lg, k, cfg, serve_cfg)
-                )(last, keys)
+            with jax.named_scope("sample"):
+                if serve_cfg.temperature <= 0.0:
+                    sampled = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                else:
+                    keys = jax.vmap(
+                        lambda u, i: jax.random.fold_in(
+                            jax.random.fold_in(base_key, u), i
+                        )
+                    )(uids, steps)
+                    sampled = jax.vmap(
+                        lambda lg, k: sample_token(lg, k, cfg, serve_cfg)
+                    )(last, keys)
             return sampled, last, pool
 
         return jax.jit(tick, donate_argnums=(1,))
@@ -446,6 +496,19 @@ class ContinuousBatchingEngine:
         if self.kv_layout == "paged":
             fns.append(self._push_row)
         return int(sum(f._cache_size() for f in fns))
+
+    def _count_compile(self, stage: str, seconds: float) -> None:
+        """One compile event (``stage`` trace | lower | backend), charged
+        to the open phase.  When recording, a lowering or backend compile
+        is also an instant on the trace's timeline (jaxpr traces, dozens
+        per eager prefill call, only add to the seconds)."""
+        phase = self.phase
+        if stage == "lower":
+            self._m_lowerings.inc(phase=phase)
+        self._m_compile_s.inc(seconds, phase=phase)
+        if self.tracer.enabled and stage != "trace":
+            self.tracer.instant("serve.compile", phase=phase, stage=stage,
+                                seconds=seconds)
 
     # -- submission ---------------------------------------------------------
 
@@ -741,6 +804,8 @@ class ContinuousBatchingEngine:
                 c = min(len(suffix) - st["done"], budget)
                 c = 1 << (int(c).bit_length() - 1)  # pow2: bounded variants
                 chunk = suffix[st["done"]:st["done"] + c]
+                self.phase = "prefill_chunk"
+                self._m_chunks.inc()
                 with self.tracer.span(
                     "serve.prefill_chunk", uid=req.uid, tokens=int(c),
                     done=st["done"] + int(c), total=len(suffix),
@@ -763,11 +828,15 @@ class ContinuousBatchingEngine:
                             self.params, st["cache"], jnp.asarray(chunk)[None],
                             moe_capacity=st["moe_cap"],
                         )
+                self.phase = "step"
                 self._m_h2d.inc(int(c) * 4)
                 st["done"] += int(c)
                 budget -= int(c)
             if st["done"] == len(suffix):
-                ev = self._finish_prefill(idx)
+                self.phase = "prefill_finish"
+                with self.tracer.span("serve.prefill_finish", uid=req.uid):
+                    ev = self._finish_prefill(idx)
+                self.phase = "step"
                 if ev is not None:
                     events.append(ev)
         return events
@@ -930,14 +999,6 @@ class ContinuousBatchingEngine:
                 "peak_kv_bytes": self.peak_used_blocks * block_bytes,
                 "preemptions": self.preemptions,
                 "peak_used_blocks": self.peak_used_blocks,
-                # counted decode traffic (ops.paged_gather_bytes): what
-                # the resolved paged backend reads from the page pool —
-                # gather adapters pay the full table window, pallas_paged
-                # pays live pages only (DESIGN.md §11)
-                "gather_bytes": self._m_gather.value(),
-                "gather_bytes_per_token": (
-                    self._m_gather.value() / max(self._m_tokens.value(), 1.0)
-                ),
             }
         rows = self.cb.num_slots * self._cache_t
         return {
@@ -965,15 +1026,89 @@ class ContinuousBatchingEngine:
     def step(self) -> List[TokenEvent]:
         """One engine tick: admit + prefill new requests (allocating KV
         blocks under the paged layout, preempting on exhaustion), then one
-        jitted decode across the pool.  Returns the tokens emitted."""
-        events: List[TokenEvent] = []
-        paged = self.kv_layout == "paged"
+        jitted decode across the pool.  Returns the tokens emitted.
 
-        # 1. admission: prefill pending requests into free slots.  Decode
-        #    state of already-active slots is untouched — they proceed on
-        #    the same tick below.  A preempted request re-prefills its
-        #    prompt plus everything it had generated.
-        for slot in self.scheduler.admit():
+        The tick is the ``serve.step`` span; each phase inside it
+        (``serve.admit``, ``serve.prefill_chunk``, ``serve.prefill_finish``,
+        ``serve.blocks``, ``serve.decode``) is a span nested in it, and
+        ``phase`` names the open one, so compiles are charged to it."""
+        if _charged is not self._ref:
+            _charge_compiles_to(self._ref)
+        tracer = self.tracer
+        events: List[TokenEvent] = []
+        self.phase = "step"
+        try:
+            with tracer.span("serve.step", step_num=self.steps):
+                # 1. admission: prefill pending requests into free slots.
+                #    Decode state of already-active slots is untouched —
+                #    they proceed on the same tick below.  A preempted
+                #    request re-prefills its prompt plus everything it had
+                #    generated.
+                slots = self.scheduler.admit()
+                if slots:
+                    self.phase = "admit"
+                    with tracer.span("serve.admit", admitted=len(slots)):
+                        self._admit(slots, events)
+                    self.phase = "step"
+
+                # 1b. chunked prefill: stream this tick's prompt-token
+                #     budget through staging slots; completed prefills join
+                #     the decode batch below (same tick — with an infinite
+                #     budget the timing matches the monolithic path exactly).
+                if self._staging:
+                    events.extend(self._run_prefill_chunks())
+
+                # 2. block upkeep: every active slot needs a home for this
+                #    tick's KV write; exhaustion preempts latest-admitted
+                #    work first.
+                if self.kv_layout == "paged":
+                    self.phase = "blocks"
+                    with tracer.span("serve.blocks"):
+                        for slot in sorted(
+                            self.scheduler.active_slots,
+                            key=lambda s: s.request.uid,
+                        ):
+                            if not slot.free:
+                                self._ensure_decode_block(slot)
+                    self.phase = "step"
+
+                # 3. one decode tick across the whole slot pool.
+                active = self.scheduler.active_slots
+                if active:
+                    self.phase = "decode"
+                    # the row sum is only computed when someone is recording
+                    args = (
+                        {"slots": len(active),
+                         "live_rows": self._live_rows(active)}
+                        if tracer.enabled else {}
+                    )
+                    with tracer.span("serve.decode", **args):
+                        self._decode(active, events)
+                    self.phase = "step"
+                    self.ticks += 1
+                self._g_queue.set(len(self.scheduler.pending))
+                self._g_active.set(len(self.scheduler.active_slots))
+                if tracer.enabled:
+                    tracer.counter(
+                        "serve.sched",
+                        pending=len(self.scheduler.pending),
+                        active=len(self.scheduler.active_slots),
+                    )
+                    if self.kv_layout == "paged":
+                        tracer.counter(
+                            "kv.blocks", used=self.block_pool.used_blocks
+                        )
+        finally:
+            self.phase = "other"
+        self.steps += 1
+        return events
+
+    def _admit(self, slots: List[Slot], events: List[TokenEvent]) -> None:
+        """Bind the slots the scheduler admitted this tick: to the staging
+        path (chunked prefill / prefix cache), or through the monolithic
+        prefill, pool write and first-token sample."""
+        paged = self.kv_layout == "paged"
+        for slot in slots:
             if slot.free:
                 continue  # preempted by an earlier admission this tick
             if self._chunked:
@@ -1023,6 +1158,7 @@ class ContinuousBatchingEngine:
             if self.tracer.enabled:
                 self.tracer.instant("serve.admit", uid=req.uid,
                                     slot=slot.index, rows=rows)
+            self.phase = "prefill"
             with self.tracer.span("serve.prefill", uid=req.uid, rows=rows):
                 logits, cache1 = self.model.prefill(
                     self.params, jnp.asarray(tokens)[None], prefill_len, **fe
@@ -1037,6 +1173,7 @@ class ContinuousBatchingEngine:
                     self._rows[slot.index] = rows
                 else:
                     self.pool = self._write_slot(self.pool, cache1, slot.index)
+            self.phase = "admit"
             self._m_d2h.inc(4)  # the admission-sampled token below
             tok = int(sample_token(
                 logits[0, -1],
@@ -1049,147 +1186,105 @@ class ContinuousBatchingEngine:
             if finished:
                 self._finish(slot)
 
-        # 1b. chunked prefill: stream this tick's prompt-token budget
-        #     through staging slots; completed prefills join the decode
-        #     batch below (same tick — with an infinite budget the timing
-        #     matches the monolithic path exactly).
-        if self._staging:
-            events.extend(self._run_prefill_chunks())
+    def _live_rows(self, active: List[Slot]) -> int:
+        """KV rows this tick's decode attends over, summed over ``active``
+        (each slot's prefix, prompt and generated tokens, the row it
+        writes this tick included; a ring holds at most its window)."""
+        return sum(
+            min(self._cache_t,
+                self._prefix_rows(self._frontend.get(s.request.uid, {}))
+                + len(s.request.prompt) + len(s.request.generated_prefix)
+                + len(s.generated))
+            for s in active
+        )
 
-        # 2. block upkeep: every active slot needs a home for this tick's
-        #    KV write; exhaustion preempts latest-admitted work first.
+    def _decode(self, active: List[Slot], events: List[TokenEvent]) -> None:
+        """Push dirty table rows, run the fused decode+sample tick, fetch
+        the sampled vector, and emit (and retire) each active slot's
+        token."""
+        paged = self.kv_layout == "paged"
+        s_count = self.cb.num_slots
         if paged:
-            for slot in sorted(
-                self.scheduler.active_slots, key=lambda s: s.request.uid
-            ):
-                if not slot.free:
-                    self._ensure_decode_block(slot)
-
-        # 3. one decode tick across the whole slot pool.
-        active = self.scheduler.active_slots
-        if active:
-            # begin/end (not a span) keeps the long decode body unnested;
-            # the uid list is only built when someone is recording
-            if self.tracer.enabled:
-                self.tracer.begin("serve.decode", tick=self.ticks,
-                                  uids=[s.request.uid for s in active])
-            s_count = self.cb.num_slots
-            if paged:
-                # flush dirty block-table rows: the only table bytes a
-                # tick uploads (steady decode uploads none)
-                for i in sorted(self._dirty_tables):
-                    self._tables_dev = self._push_row(
-                        self._tables_dev, jnp.int32(i),
-                        jnp.asarray(self._tables[i]),
-                    )
-                    self._m_h2d.inc(self._slot_blocks * 4)
-                self._dirty_tables.clear()
-                tables = self._tables_dev
-            else:
-                tables = None
-            if self._serve_cfg.temperature > 0.0:
-                # full-pool uid/step vectors: free slots derive garbage
-                # keys whose draws are discarded below
-                uv = np.zeros(s_count, np.int32)
-                sv = np.zeros(s_count, np.int32)
-                for s in active:
-                    uv[s.index] = s.request.uid
-                    sv[s.index] = (
-                        len(s.request.generated_prefix) + len(s.generated)
-                    )
-                uids, steps = jnp.asarray(uv), jnp.asarray(sv)
-                self._m_h2d.inc(2 * s_count * 4)
-            else:
-                uids = steps = None
-            # decode + sample fused in one program; ``last`` stays on
-            # device unless the guard path needs it
-            sampled_dev, last, self.pool = self._tick(
-                self.params, self.pool, jnp.asarray(self._inputs),
-                tables, uids, steps,
-            )
-            self.last_logits = last
-            self._m_h2d.inc(self._inputs.size * 4)
-            if paged:
-                for slot in active:
-                    self._rows[slot.index] += 1
-            spec = self.cfg.softmax_spec
-            if (
-                self.guard is not None
-                and self._serve_cfg.temperature > 0.0
-                and self._serve_cfg.star_sampling
-                and spec.kind != "exact"
-            ):
-                # guard needs concrete arrays: one batched eager softmax
-                # over all active rows (a single oracle check per tick),
-                # then the per-slot categorical draws — this path fetches
-                # the logits row block, trading the single-transfer tick
-                # for the host-side oracle comparison
-                rows_ix = jnp.asarray([s.index for s in active])
-                keys = jax.vmap(lambda u, i: jax.random.fold_in(
-                    jax.random.fold_in(self._base_key, u), i))(
-                        jnp.asarray([s.request.uid for s in active]),
-                        jnp.asarray([
-                            len(s.request.generated_prefix) + len(s.generated)
-                            for s in active
-                        ]))
-                scaled = (
-                    last[rows_ix].astype(jnp.float32)
-                    / self._serve_cfg.temperature
+            # flush dirty block-table rows: the only table bytes a
+            # tick uploads (steady decode uploads none)
+            for i in sorted(self._dirty_tables):
+                self._tables_dev = self._push_row(
+                    self._tables_dev, jnp.int32(i),
+                    jnp.asarray(self._tables[i]),
                 )
-                probs = ops.softmax(scaled, spec, guard=self.guard)
-                logp = jnp.log(jnp.maximum(probs, 1e-20))
-                sampled = np.asarray(jax.vmap(
-                    lambda k, lg: jax.random.categorical(k, lg, axis=-1)
-                )(keys, logp)).astype(np.int32)
-                self._m_d2h.inc(int(sampled.size) * 4 + len(active) * 4)
-                toks = {s.index: int(t) for s, t in zip(active, sampled)}
-            else:
-                # the tick's single D2H transfer: the sampled-token vector
-                sampled = np.asarray(sampled_dev)
-                self._m_d2h.inc(int(sampled.size) * 4)
-                toks = {s.index: int(sampled[s.index]) for s in active}
-            if paged:
-                impl = (
-                    active_overrides("paged_attention").get("impl")
-                    or self.cfg.paged_attention_spec.impl
+                self._m_h2d.inc(self._slot_blocks * 4)
+            self._dirty_tables.clear()
+            tables = self._tables_dev
+        else:
+            tables = None
+        if self._serve_cfg.temperature > 0.0:
+            # full-pool uid/step vectors: free slots derive garbage
+            # keys whose draws are discarded below
+            uv = np.zeros(s_count, np.int32)
+            sv = np.zeros(s_count, np.int32)
+            for s in active:
+                uv[s.index] = s.request.uid
+                sv[s.index] = (
+                    len(s.request.generated_prefix) + len(s.generated)
                 )
-                pk = self.pool["layers"]["k"]
-                quantized = "k_scale" in self.pool["layers"]
-                self._m_gather.inc(pk.shape[0] * ops.paged_gather_bytes(
-                    impl,
-                    table_width=self._slot_blocks,
-                    block_size=self.block_pool.block_size,
-                    live_lens=np.minimum(self._rows, self._cache_t),
-                    num_kv_heads=pk.shape[3],
-                    head_dim=pk.shape[4],
-                    dtype_bytes=pk.dtype.itemsize,
-                    # per-layer K+V scale rows a quantized read touches
-                    scale_bytes_per_block=(8 * pk.shape[3]) if quantized else 0,
-                ))
+            uids, steps = jnp.asarray(uv), jnp.asarray(sv)
+            self._m_h2d.inc(2 * s_count * 4)
+        else:
+            uids = steps = None
+        # decode + sample fused in one program; ``last`` stays on
+        # device unless the guard path needs it
+        sampled_dev, last, self.pool = self._tick(
+            self.params, self.pool, jnp.asarray(self._inputs),
+            tables, uids, steps,
+        )
+        self.last_logits = last
+        self._m_h2d.inc(self._inputs.size * 4)
+        if paged:
             for slot in active:
-                tok = toks[slot.index]
-                finished = self.scheduler.record_token(slot, tok)
-                events.append(self._emit(slot, tok, finished))
-                self._inputs[slot.index, 0] = tok
-                if finished:
-                    self._finish(slot)
-            if self.tracer.enabled:
-                self.tracer.end("serve.decode")
-            self.ticks += 1
-        self._g_queue.set(len(self.scheduler.pending))
-        self._g_active.set(len(self.scheduler.active_slots))
-        self._g_jit.set(self.jit_cache_entries())
-        if self.tracer.enabled:
-            self.tracer.counter(
-                "serve.sched",
-                pending=len(self.scheduler.pending),
-                active=len(self.scheduler.active_slots),
+                self._rows[slot.index] += 1
+        spec = self.cfg.softmax_spec
+        if (
+            self.guard is not None
+            and self._serve_cfg.temperature > 0.0
+            and self._serve_cfg.star_sampling
+            and spec.kind != "exact"
+        ):
+            # guard needs concrete arrays: one batched eager softmax
+            # over all active rows (a single oracle check per tick),
+            # then the per-slot categorical draws — this path fetches
+            # the logits row block, trading the single-transfer tick
+            # for the host-side oracle comparison
+            rows_ix = jnp.asarray([s.index for s in active])
+            keys = jax.vmap(lambda u, i: jax.random.fold_in(
+                jax.random.fold_in(self._base_key, u), i))(
+                    jnp.asarray([s.request.uid for s in active]),
+                    jnp.asarray([
+                        len(s.request.generated_prefix) + len(s.generated)
+                        for s in active
+                    ]))
+            scaled = (
+                last[rows_ix].astype(jnp.float32)
+                / self._serve_cfg.temperature
             )
-            if paged:
-                self.tracer.counter(
-                    "kv.blocks", used=self.block_pool.used_blocks
-                )
-        return events
+            probs = ops.softmax(scaled, spec, guard=self.guard)
+            logp = jnp.log(jnp.maximum(probs, 1e-20))
+            sampled = np.asarray(jax.vmap(
+                lambda k, lg: jax.random.categorical(k, lg, axis=-1)
+            )(keys, logp)).astype(np.int32)
+            self._m_d2h.inc(int(sampled.size) * 4 + len(active) * 4)
+            toks = {s.index: int(t) for s, t in zip(active, sampled)}
+        else:
+            # the tick's single D2H transfer: the sampled-token vector
+            sampled = np.asarray(sampled_dev)
+            self._m_d2h.inc(int(sampled.size) * 4)
+            toks = {s.index: int(sampled[s.index]) for s in active}
+        for slot in active:
+            tok = toks[slot.index]
+            finished = self.scheduler.record_token(slot, tok)
+            events.append(self._emit(slot, tok, finished))
+            self._inputs[slot.index, 0] = tok
+            if finished:
+                self._finish(slot)
 
     # -- draining -----------------------------------------------------------
 

@@ -306,7 +306,7 @@ def test_engine_int8_kernel_matches_int8_oracle(arch, lens):
     want, _ = _serve(cfg, params, prompts, gens, "int8", "xla")
     assert got == want
     st = eng.kv_stats()
-    assert st["kv_dtype"] == "int8" and st["gather_bytes_per_token"] > 0
+    assert st["kv_dtype"] == "int8"
 
 
 def test_engine_int8_vlm_mrope_parity():

@@ -48,15 +48,69 @@ def test_span_records_complete_event_with_fake_clock():
 def test_begin_end_and_instant_and_counter_events():
     clk = FakeClock()
     tr = obs.Tracer(clock=clk)
-    tr.begin("decode", tick=0)
     clk.advance(0.5)
-    tr.end("decode")
     tr.instant("preempt", uid=3)
     tr.counter("sched", pending=2, active=4)
     phs = [e.ph for e in tr.events]
-    assert phs == ["B", "E", "i", "C"]
-    assert tr.events[0].args == {"tick": 0}
-    assert tr.events[3].args == {"pending": 2, "active": 4}
+    assert phs == ["i", "C"]
+    assert tr.events[0].ts == pytest.approx(0.5e6)
+    assert tr.events[0].args == {"uid": 3}
+    assert tr.events[1].args == {"pending": 2, "active": 4}
+    assert not hasattr(tr, "begin") and not hasattr(tr, "end")
+
+
+class _Recorder:
+    """An annotation sink that logs what was entered and left."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **args):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("enter", name, args))
+
+            def __exit__(self, *exc):
+                rec.log.append(("exit", name))
+
+        return _Ann()
+
+
+def test_span_enters_the_annotation_sink_with_scalar_args_only():
+    clk = FakeClock()
+    sink = _Recorder()
+    tr = obs.Tracer(clock=clk, annotate=sink)
+    with tr.span("outer", step_num=3):
+        with tr.span("inner", uid=7, uids=[1, 2], label="x", share=0.5):
+            clk.advance(0.25)
+    assert sink.log == [
+        ("enter", "outer", {"step_num": 3}),
+        # the list stays in the ring buffer only
+        ("enter", "inner", {"uid": 7, "label": "x", "share": 0.5}),
+        ("exit", "inner"),
+        ("exit", "outer"),
+    ]
+    inner, outer = tr.events  # appended on exit: innermost first
+    assert inner.args["uids"] == [1, 2]
+    assert (outer.name, outer.args) == ("outer", {"step_num": 3})
+    assert inner.dur == pytest.approx(0.25e6)
+
+
+def test_enable_tracing_installs_the_sink_and_the_null_tracer_has_none():
+    sink = _Recorder()
+    tr = obs.enable_tracing(capacity=8, annotate=sink)
+    assert tr.annotate is sink
+    with tr.span("a"):
+        pass
+    assert [entry[0] for entry in sink.log] == ["enter", "exit"]
+    obs.disable_tracing()
+    null = obs.get_tracer()
+    assert null.annotate is None
+    with null.span("b", uid=1):
+        pass
+    assert len(sink.log) == 2 and null.events == []
 
 
 def test_async_events_carry_correlation_id():
@@ -112,8 +166,6 @@ def test_null_tracer_is_free_and_global_swap_roundtrips():
     assert s1 is s2
     with s1:
         pass
-    null.begin("x")
-    null.end("x")
     null.instant("y")
     null.counter("z", v=1)
     null.async_begin("r", 0)

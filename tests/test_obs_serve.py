@@ -231,13 +231,19 @@ def test_serve_trace_has_spans_for_every_request_and_loads_as_chrome_json(
     prefills = by_name["serve.prefill"]
     assert all(p["ph"] == "X" and p["dur"] >= 0 for p in prefills)
     assert sorted(p["args"]["uid"] for p in prefills) == sorted(uids)
-    # decode B/E events balance and cover every request's uid
+    # one decode span per tick, nested in its serve.step span; together
+    # they count every decode token (2-token answers: one each) and the
+    # rows attended (prompt + the token written: 5, 6, 7)
     decode = by_name["serve.decode"]
-    assert sum(e["ph"] == "B" for e in decode) == \
-        sum(e["ph"] == "E" for e in decode) > 0
-    decoded_uids = {u for e in decode if e["ph"] == "B"
-                    for u in e["args"]["uids"]}
-    assert decoded_uids == set(uids)
+    assert all(e["ph"] == "X" for e in decode) and len(decode) == eng.ticks
+    assert sum(e["args"]["slots"] for e in decode) == len(uids)
+    assert sum(e["args"]["live_rows"] for e in decode) == 5 + 6 + 7
+    steps = by_name["serve.step"]
+    assert [e["args"]["step_num"] for e in steps] == list(range(eng.steps))
+    for e in decode:
+        assert any(st["ts"] <= e["ts"]
+                   and e["ts"] + e["dur"] <= st["ts"] + st["dur"]
+                   for st in steps)
     # one async request track per uid, opened and closed
     req = by_name["request"]
     for uid in uids:

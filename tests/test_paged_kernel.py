@@ -263,8 +263,6 @@ def test_engine_greedy_parity_pallas_paged(arch, lens):
         uids = [eng.submit(p, g) for p, g in zip(prompts, gens)]
         done = eng.run()
     assert [done[u] for u in uids] == expected
-    # the engine accounted gather-free traffic for the resolved impl
-    assert eng.kv_stats()["gather_bytes_per_token"] > 0
 
 
 def test_engine_vlm_mrope_parity_pallas_paged():

@@ -136,25 +136,3 @@ def test_steady_decode_uploads_no_table_bytes(model):
     inputs_only = sum(1 for d in deltas if d <= eng._inputs.size * 4)
     assert inputs_only >= len(deltas) // 2
     assert all(d <= eng._inputs.size * 4 + w_bytes for d in deltas)
-
-
-def test_gather_bytes_counter_tracks_backend(model):
-    """The kv.gather.bytes counter scales with the resolved backend: the
-    gather adapters pay the full table window, pallas_paged pays live
-    pages — the serve-level form of the BENCH_paged_decode speedup."""
-    from repro import ops
-
-    cfg, params = model
-    p = RNG.integers(0, cfg.vocab_size, (5,)).astype(np.int32)
-
-    def bytes_per_token(**use):
-        with ops.use(**use):
-            eng = _engine(cfg, params)
-            eng.submit(p, 6)
-            eng.run()
-        return eng.kv_stats()["gather_bytes_per_token"]
-
-    gathered = bytes_per_token()  # config default: xla gather adapter
-    paged = bytes_per_token(paged_attention="pallas_paged")
-    assert paged < gathered
-    assert gathered / paged >= 1.5
