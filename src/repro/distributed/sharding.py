@@ -144,6 +144,17 @@ def current_mesh_rules():
     return getattr(_ctx, "state", None)
 
 
+def mesh_rules_key():
+    """The ambient (mesh, rules) as a hashable key, rules as
+    ``logical_to_pspec`` reads them: a jit cache keyed by it traces anew
+    under another mesh context."""
+    state = current_mesh_rules()
+    if state is None:
+        return None
+    mesh, rules = state
+    return mesh, tuple(sorted((k, _normalize(v)) for k, v in rules.items()))
+
+
 def with_logical_constraint(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     """Apply a sharding constraint if a mesh context is active, else no-op."""
     state = current_mesh_rules()

@@ -18,9 +18,12 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import kvquant
+from repro.core.scan_ctl import unroll_scans_enabled
+from repro.distributed.sharding import mesh_rules_key
 from repro.distributed.sharding import with_logical_constraint as wlc
 from repro.models import layers as L
 from repro.models.param import ParamSpec
+from repro.ops.registry import override_key
 
 Params = Dict[str, Any]
 
@@ -34,9 +37,33 @@ def _stack_specs(spec: Params, n: int) -> Params:
     )
 
 
+def _trace_context() -> Tuple:
+    """What a trace of the model reads besides its arguments: the
+    ``ops.use`` override stack, the scan-unroll probe flag and the ambient
+    mesh rules.  The jitted prompt programs take it as a static argument,
+    so a call under another context traces anew, as an eager call did."""
+    return override_key(), unroll_scans_enabled(), mesh_rules_key()
+
+
 class DecoderLM:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg.validate()
+
+        # The prompt programs, jitted once per model (DESIGN.md §12): a
+        # call whose shapes, static arguments and ``context`` were seen
+        # before runs its compiled program, with no trace, lowering or
+        # compile-cache load on the host.  ``context`` only keys the cache.
+        # Nothing is donated: a staging cache may be extended twice.
+        def prefill(params, tokens, patch_embeds, cache_t, moe_capacity, context):
+            return self._prefill(params, tokens, patch_embeds, cache_t, moe_capacity)
+
+        def prefill_extend(params, cache, tokens, moe_capacity, context):
+            return self._prefill_extend(params, cache, tokens, moe_capacity)
+
+        self.prompt_programs = (
+            jax.jit(prefill, static_argnames=("cache_t", "moe_capacity", "context")),
+            jax.jit(prefill_extend, static_argnames=("moe_capacity", "context")),
+        )
 
     # -- parameters ---------------------------------------------------------
 
@@ -494,11 +521,18 @@ class DecoderLM:
         ``moe_capacity`` threads the full-sequence expert capacity through
         (and adds per-layer ``moe`` queue counts to the returned cache) so
         a chunked MoE prefill drops exactly the tokens a monolithic one
-        would.
+        would.  Runs through ``prompt_programs[0]``: one compile per
+        distinct shape, capacity and trace context.
         """
+        ct = cache_t if cache_t is not None else self.cache_len(max_len)
+        return self.prompt_programs[0](
+            params, tokens, patch_embeds, cache_t=ct,
+            moe_capacity=moe_capacity, context=_trace_context(),
+        )
+
+    def _prefill(self, params, tokens, patch_embeds, ct, moe_capacity):
         cfg = self.cfg
-        b, t = tokens.shape
-        x, positions, n_prefix = self._embed_inputs(params, tokens, patch_embeds)
+        x, positions, _ = self._embed_inputs(params, tokens, patch_embeds)
 
         def body(carry, bp):
             out, _, (k, v), ms = self._block(
@@ -516,7 +550,6 @@ class DecoderLM:
         h = L.rmsnorm(params["final_norm"], h, cfg.norm_eps)
         logits = L.unembed(params["unembed"], h[:, -1:], cfg, params["embed"])
 
-        ct = cache_t if cache_t is not None else self.cache_len(max_len)
         seq = x.shape[1]
         if cfg.sliding_window is None and seq > ct:
             raise ValueError(
@@ -556,7 +589,14 @@ class DecoderLM:
         bit-identity contract chunked serving relies on (DESIGN.md §12).
         Requires the staging buffer to be strictly longer than the sliding
         window (the ring in-place path only supports single-token writes).
+        Runs through ``prompt_programs[1]``, which donates nothing.
         """
+        return self.prompt_programs[1](
+            params, cache, tokens, moe_capacity=moe_capacity,
+            context=_trace_context(),
+        )
+
+    def _prefill_extend(self, params, cache, tokens, moe_capacity):
         cfg = self.cfg
         b, c = tokens.shape
         x = L.embed(params["embed"], tokens, cfg)

@@ -179,6 +179,12 @@ def use(**overrides: Any) -> Iterator[None]:
         _OVERRIDE_FRAMES.reset(token)
 
 
+def override_key() -> Tuple[Tuple[Tuple[str, Any], ...], ...]:
+    """The override stack as a hashable key: a jit cache keyed by it traces
+    anew under other overrides, as the overrides resolve at trace time."""
+    return tuple(tuple(sorted(f.items())) for f in _OVERRIDE_FRAMES.get())
+
+
 def active_overrides(op: str) -> Dict[str, Any]:
     """Collapse the override stack for one op: {'impl': ..., 'interpret': ...}."""
     out: Dict[str, Any] = {}

@@ -303,7 +303,7 @@ class ContinuousBatchingEngine:
             "serve.compile.seconds", "seconds of jaxpr trace, MLIR lowering "
             "and backend compile or cache load, by phase")
         self._m_chunks = reg.counter(
-            "serve.prefill.chunks", "eager prefill chunk calls")
+            "serve.prefill.chunks", "prefill chunk calls")
         self.phase = "other"
         self.steps = 0  # step() calls: the profiler's step number
         self._ref = weakref.ref(self)
@@ -485,10 +485,11 @@ class ContinuousBatchingEngine:
 
     def jit_cache_entries(self) -> int:
         """Pooled compiled-variant count across the engine's jitted
-        callables — the retrace observable (tests/test_serve_retrace.py):
+        callables, its model's prompt programs included — the retrace
+        observable (tests/test_serve_retrace.py):
         a repeated workload must not grow it, and mixed-length paged
         traffic must grow the admission write O(log W), not O(n)."""
-        fns = [self._tick, self._reset_slot]
+        fns = [self._tick, self._reset_slot, *self.model.prompt_programs]
         fns.append(
             self._write_slot_paged if self.kv_layout == "paged"
             else self._write_slot
@@ -500,8 +501,8 @@ class ContinuousBatchingEngine:
     def _count_compile(self, stage: str, seconds: float) -> None:
         """One compile event (``stage`` trace | lower | backend), charged
         to the open phase.  When recording, a lowering or backend compile
-        is also an instant on the trace's timeline (jaxpr traces, dozens
-        per eager prefill call, only add to the seconds)."""
+        is also an instant on the trace's timeline (jaxpr traces only add
+        to the seconds)."""
         phase = self.phase
         if stage == "lower":
             self._m_lowerings.inc(phase=phase)

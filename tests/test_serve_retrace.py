@@ -6,6 +6,10 @@ The device-resident engine tick makes two quantitative promises:
   (``serve.paged.bucket_blocks``), so a mixed-length paged workload
   compiles O(log W) admission-write variants, not one per block count;
   and a *repeated* workload compiles nothing at all.
+* **Prompt programs compiled once** — the model's ``prefill`` and
+  ``prefill_extend`` are jitted per model instance, so a chunk of a
+  ``(length, staging rows, capacity)`` seen before lowers nothing, whether
+  the engine or a direct call (the benchmark's warm-up) saw it first.
 * **Bounded transfers** — a steady tick performs one D2H transfer (the
   ``[S]`` sampled-token vector) and uploads no block-table bytes unless
   the allocator dirtied a row; the ``serve.bytes.h2d`` / ``serve.bytes.d2h``
@@ -20,8 +24,11 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
+from repro import ops
 from repro.configs import get_smoke_config
+from repro.core.scan_ctl import unroll_scans
 from repro.models.param import materialize
 from repro.models.registry import build_model
 from repro.serve.engine import ContinuousBatchingEngine, ContinuousConfig
@@ -113,6 +120,99 @@ def test_repeat_workload_zero_new_compilations_bounded_d2h(model):
     # one prefill-sampled token
     assert d2h2 <= ticks2 * SLOTS * 4 + admits2 * 4
     assert d2h2 / max(ticks2, 1) <= (SLOTS + SLOTS) * 4
+
+
+def _prefill_lowerings(eng):
+    return eng.metrics.counter("serve.compile.lowerings").value(
+        phase="prefill_chunk")
+
+
+def test_repeat_chunked_prefill_lowers_nothing(model):
+    """A second identical chunked-prefill workload on the SAME engine
+    lowers no program while a chunk runs, and adds no jit entry — the
+    prompt programs are counted among the engine's entries."""
+    cfg, params = model
+    eng = _engine(cfg, params, prefill_chunk_tokens=4)
+    prompts, gens = _mixed_workload(cfg, n=8)
+
+    def run_once():
+        for p, g in zip(prompts, gens):
+            eng.submit(p, g)
+        eng.run()
+
+    run_once()
+    entries = eng.jit_cache_entries()
+    programs = sum(f._cache_size() for f in eng.model.prompt_programs)
+    assert programs >= 2  # a first chunk and an extend at least
+    chunks0 = eng.metrics.counter("serve.prefill.chunks").value()
+    lowered0 = _prefill_lowerings(eng)
+    assert lowered0 >= programs
+    run_once()
+    assert eng.metrics.counter("serve.prefill.chunks").value() > chunks0
+    assert _prefill_lowerings(eng) == lowered0
+    assert eng.jit_cache_entries() == entries
+
+
+@pytest.mark.parametrize("arch", ["granite_8b", "granite_moe_1b_a400m"])
+def test_direct_model_calls_warm_the_engine_chunks(arch):
+    """Calling ``model.prefill`` / ``prefill_extend`` directly, as the
+    benchmark's warm-up does, with each later chunk's ``(chunk, staging
+    rows, capacity)`` leaves the engine's chunks nothing to lower; and an
+    extend may run twice on one staging cache (nothing is donated)."""
+    cfg = get_smoke_config(arch)
+    params = materialize(build_model(cfg).param_specs(), KEY)
+    eng = _engine(cfg, params, prefill_chunk_tokens=4)
+    prompt = RNG.integers(0, cfg.vocab_size, (10,)).astype(np.int32)
+    ts = eng._staging_rows(len(prompt))
+    cap = eng.model.moe_prefill_capacity(len(prompt))
+    assert (cap is None) == (arch == "granite_8b")
+    m = eng.model
+
+    def chunk(n):
+        return jnp.asarray(np.zeros(n, np.int32))[None]
+
+    # 10 tokens at a budget of 4: a first chunk of 4, extends of 4 and 2
+    _, base = m.prefill(eng.params, chunk(4), eng.cb.max_len, cache_t=ts,
+                        moe_capacity=cap)
+    first = m.prefill_extend(eng.params, base, chunk(4), moe_capacity=cap)
+    again = m.prefill_extend(eng.params, base, chunk(4), moe_capacity=cap)
+    for a, b in zip(jax.tree.leaves(first), jax.tree.leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    m.prefill_extend(eng.params, base, chunk(2), moe_capacity=cap)
+    entries = eng.jit_cache_entries()
+
+    eng.submit(prompt, 3)
+    eng.step()  # admission + the first chunk
+    assert eng.metrics.counter("serve.prefill.chunks").value() == 1
+    eng.run()
+    assert eng.metrics.counter("serve.prefill.chunks").value() == 3
+    assert _prefill_lowerings(eng) == 0
+    assert sum(f._cache_size() for f in m.prompt_programs) == 3
+    assert eng.jit_cache_entries() > entries  # the tick and pool writes
+
+
+def test_prompt_programs_trace_anew_under_another_context(model):
+    """Overrides and the scan-unroll probe flag resolve at trace time, so
+    they key the prompt programs' cache: a call under another context
+    compiles its own program, a call under a seen one reuses it."""
+    cfg, params = model
+    m = build_model(cfg)
+    toks = jnp.asarray(RNG.integers(0, cfg.vocab_size, (1, 6)), jnp.int32)
+    size = m.prompt_programs[0]._cache_size
+
+    want, _ = m.prefill(params, toks, 8)
+    m.prefill(params, toks, 8)
+    assert size() == 1
+    with ops.use(softmax="xla"):
+        m.prefill(params, toks, 8)
+    assert size() == 2
+    with unroll_scans():
+        got, _ = m.prefill(params, toks, 8)
+    assert size() == 3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    m.prefill(params, toks, 8)
+    assert size() == 3
 
 
 def test_steady_decode_uploads_no_table_bytes(model):
