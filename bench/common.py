@@ -9,7 +9,22 @@ gives it:
 * ``bench/kinds/<kind>.py``        a kind of traffic the generator and the
   client know (``open_loop``, ``backlog``);
 * ``bench/metrics/<metric>.py``    a reader with ``read(ctx)``;
-* ``bench/correct/reference_<name>.py``  a configuration's plain reference.
+* ``bench/correct/reference_<name>.py``  the architecture a configuration
+  names under ``reference``: the one place that knows it (below).
+
+An architecture module provides
+
+* ``FIELDS``: each key its configurations' ``model`` group may hold ->
+  the system's ``ModelConfig`` field, or a function of the ``ModelConfig``
+  where the system derives the value (``run.system_config`` stops a run
+  on a key the table does not map);
+* ``dims_of(model)``: the sizes the weights, the reference and the metric
+  readers compute with, ``"V"`` (the vocabulary) among them;
+* ``layout(dims)``: the weight tree as ``(shape, init)`` leaves
+  (``bench/weights.py``);
+* ``logits(params, tokens, dims, compute=None)``: the plain reference.
+
+So a configuration of a new architecture arrives as new files only.
 """
 
 from __future__ import annotations

@@ -1,8 +1,15 @@
-"""Plain reference of the decoder the two configurations run.
+"""The decoder architecture that the configurations run: its key table,
+sizes, weight layout and plain reference.
 
-Straightforward ``jax.numpy`` in float32 at ``highest`` matmul precision:
-a full causal forward pass over one sequence, with no cache, no kernel,
-no batching and no import from the system under test.  It follows the
+``FIELDS`` maps each key a configuration's ``model`` group may hold to the
+system's ``ModelConfig`` field, or to a function of the ``ModelConfig``
+for a value the system derives; ``dims_of`` gives the sizes the weights,
+the reference and the metric readers compute with; ``layout`` the weight
+tree as ``(shape, init)`` leaves (``bench/weights.py``).
+
+The reference is straightforward ``jax.numpy`` in float32 at ``highest``
+matmul precision: a full causal forward pass over one sequence, with no
+cache, no kernel, no batching and no import from the system under test.  It follows the
 system's stated mathematics:
 
 * token embedding, pre-norm blocks, RMSNorm (no mean), final RMSNorm,
@@ -33,6 +40,84 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+
+def _softmax(cfg) -> Dict[str, Any]:
+    sm = cfg.softmax_spec
+    got = {"kind": sm.kind, "int_bits": sm.fmt.int_bits, "frac_bits": sm.fmt.frac_bits}
+    if cfg.family == "moe":
+        got["router"] = cfg.star_router
+    return got
+
+
+# model key in a configuration file -> the system's ModelConfig field, or
+# a function of the ModelConfig where the system derives the value
+FIELDS = {
+    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "tie_word_embeddings": "tie_embeddings", "num_local_experts": "num_experts",
+    "num_experts_per_tok": "top_k", "capacity_factor": "capacity_factor",
+    "param_dtype": "param_dtype", "compute_dtype": "compute_dtype",
+    "family": "family",
+    "head_dim": lambda cfg: cfg.resolved_head_dim,
+    "softmax": _softmax,
+}
+
+
+def dims_of(model: Dict[str, Any]) -> Dict[str, Any]:
+    """The sizes the benchmark computes with, from a configuration file's
+    ``model`` group (Hugging Face key names)."""
+    vocab = model["vocab_size"]
+    return {
+        "family": model["family"],
+        "L": model["num_hidden_layers"],
+        "d": model["hidden_size"],
+        "Hq": model["num_attention_heads"],
+        "Hkv": model["num_key_value_heads"],
+        "D": model["head_dim"],
+        "f": model["intermediate_size"],
+        "V": vocab,
+        "Vp": -(-vocab // 512) * 512,  # the system pads the vocabulary to 512
+        "E": model.get("num_local_experts", 0),
+        "K": model.get("num_experts_per_tok", 0),
+        "rope_theta": model["rope_theta"],
+        "eps": model["rms_norm_eps"],
+        "int_bits": model["softmax"]["int_bits"],
+        "frac_bits": model["softmax"]["frac_bits"],
+    }
+
+
+def layout(dims: Dict[str, Any]) -> Dict[str, Any]:
+    """The system's parameter tree: stacked pre-norm blocks with a dense
+    SwiGLU FFN or routed SwiGLU experts."""
+    L, d, D = dims["L"], dims["d"], dims["D"]
+    hq, hkv, f = dims["Hq"] * D, dims["Hkv"] * D, dims["f"]
+    mat = "fan_in"
+    block: Dict[str, Any] = {
+        "ln1": {"scale": ((L, d), "ones")},
+        "attn": {
+            "wq": ((L, d, hq), mat), "wk": ((L, d, hkv), mat),
+            "wv": ((L, d, hkv), mat), "wo": ((L, hq, d), mat),
+        },
+        "ln2": {"scale": ((L, d), "ones")},
+    }
+    if dims["family"] == "moe":
+        e = dims["E"]
+        block["moe"] = {
+            "router": ((L, d, e), mat), "wi": ((L, e, d, f), mat),
+            "wg": ((L, e, d, f), mat), "wo": ((L, e, f, d), mat),
+        }
+    else:
+        block["mlp"] = {"wi": ((L, d, f), mat), "wg": ((L, d, f), mat),
+                        "wo": ((L, f, d), mat)}
+    return {
+        "embed": {"table": ((dims["Vp"], d), "embed")},
+        "blocks": block,
+        "final_norm": {"scale": ((d,), "ones")},
+        "unembed": {"kernel": ((d, dims["Vp"]), mat)},
+    }
 
 
 def _low(x, compute: Optional[str]):

@@ -1,7 +1,7 @@
 """Device milliseconds per thousand prompt tokens prefilled: every program
 the engine runs outside its decode tick, pool write, table push and slot
-reset (the eager chunked prefill with its layer scan, and the first
-token's sampling), over the prompt tokens of the engine's own
+reset (the chunked prefill's ``jit_prefill`` and ``jit_prefill_extend``,
+one program per chunk shape, and the first token's sampling), over the prompt tokens of the engine's own
 ``serve.prefill_chunk`` spans in the traced window."""
 
 from bench import trace_reduce

@@ -1,4 +1,5 @@
-"""Programs lowered to MLIR per eager prefill chunk in the traced window:
+"""Programs lowered to MLIR per prefill chunk in the traced window (0
+once every chunk shape is warm, as the prefill is jitted once per shape):
 the engine's ``serve.compile`` lowering events charged to its
 ``prefill_chunk`` phase (the events behind its
 ``serve.compile.lowerings{phase=prefill_chunk}`` counter) over its

@@ -7,8 +7,9 @@ import dataclasses
 from typing import Any, Dict, List, Optional
 
 # The engine's own jitted programs, by the module names the trace gives
-# them; every other program a tick runs is prefill work (the eager
-# prefill, its layer scan, and the first token's sampling).
+# them; every other program a tick runs is prefill work (the model's
+# ``jit_prefill`` and ``jit_prefill_extend``, one program per chunk shape,
+# and the first token's sampling).
 TICK = "jit_tick"
 ENGINE_PROGRAMS = ("jit_tick", "jit_write_slot_paged", "jit__lambda",
                    "jit_reset_slot")
@@ -16,7 +17,7 @@ ENGINE_PROGRAMS = ("jit_tick", "jit_write_slot_paged", "jit__lambda",
 
 @dataclasses.dataclass
 class Readings:
-    dims: Dict[str, Any]  # weights.dims_of(config["model"])
+    dims: Dict[str, Any]  # the architecture module's dims_of(config["model"])
     num_slots: int
     kv_itemsize: int  # bytes per stored K or V element
     act_itemsize: int  # bytes per element of the compute dtype

@@ -35,6 +35,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Any, Callable, Dict, Optional, Sequence  # noqa: E402
 
 sys.path[:0] = [str(Path(__file__).resolve().parents[1]),
@@ -49,19 +50,6 @@ from bench.readings import Readings  # noqa: E402
 TRACE_SECONDS = 15.0  # traced span of a --trace 1 window (at most)
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
-
-# model key in a configuration file -> the system's ModelConfig field
-MODEL_FIELDS = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings", "num_local_experts": "num_experts",
-    "num_experts_per_tok": "top_k", "capacity_factor": "capacity_factor",
-    "param_dtype": "param_dtype", "compute_dtype": "compute_dtype",
-    "family": "family",
-}
-
 
 class CompileCount:
     """XLA compilations, from ``jax.monitoring``: every compile request
@@ -108,24 +96,21 @@ def enable_compile_cache(jax) -> str:
     return path
 
 
-def system_config(config: Dict[str, Any]):
-    """The system's ModelConfig for ``config``: its architecture's config
-    with every size the file states put in, then checked field by field."""
+def system_config(config: Dict[str, Any], arch: ModuleType):
+    """The system's ModelConfig for ``config``: the system's own config
+    ``system_arch`` with every field that the architecture's ``FIELDS``
+    names put in, then every key of the file's ``model`` group checked
+    against the system, field by field.  A key that the table does not map
+    stops the run."""
     from repro.configs import get_config
 
     model = config["model"]
-    cfg = get_config(config["system_arch"])
-    cfg = dataclasses.replace(cfg, **{
-        MODEL_FIELDS[k]: v for k, v in model.items() if k in MODEL_FIELDS})
-    got = {k: getattr(cfg, MODEL_FIELDS[k]) for k in model if k in MODEL_FIELDS}
-    got["head_dim"] = cfg.resolved_head_dim
-    sm = cfg.softmax_spec
-    got["softmax"] = {"kind": sm.kind, "int_bits": sm.fmt.int_bits,
-                      "frac_bits": sm.fmt.frac_bits}
-    want = dict(model)
-    if model["family"] == "moe":
-        got["softmax"]["router"] = cfg.star_router
-    bad = {k: (want[k], got.get(k)) for k in want if want[k] != got.get(k)}
+    fields = {k: f for k, f in arch.FIELDS.items() if k in model}
+    cfg = dataclasses.replace(get_config(config["system_arch"]), **{
+        f: model[k] for k, f in fields.items() if isinstance(f, str)})
+    got = {k: getattr(cfg, f) if isinstance(f, str) else f(cfg)
+           for k, f in fields.items()}
+    bad = {k: (v, got.get(k)) for k, v in model.items() if v != got.get(k)}
     if bad:
         raise SystemExit(f"the system does not run {config['name']} as stated: "
                          f"{bad} (file, system)")
@@ -170,13 +155,12 @@ def find_xplane(root: str) -> Optional[str]:
     return str(found[-1]) if found else None
 
 
-def reference_check(config, dims, params, client, mix, seed: int,
+def reference_check(ref: ModuleType, dims, params, client, mix, seed: int,
                     control: Optional[str] = None) -> Dict[str, Any]:
-    """Per sampled request, the widest gap of its served tokens; with
-    ``control`` (a dtype) also the widest gap of the tokens that the
-    reference computed in that lower precision, in the system's place,
-    ranks first at the same positions."""
-    ref = common.reference_module(config["reference"])
+    """Per sampled request, the widest gap of its served tokens by the
+    architecture's reference ``ref``; with ``control`` (a dtype) also the
+    widest gap of the tokens that the reference computed in that lower
+    precision, in the system's place, ranks first at the same positions."""
     finished = {r.uid: r for r in client.served.values() if r.finished}
     keys = compare.sample({k: {"tokens": r.tokens} for k, r in finished.items()}, seed)
     length = mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"]
@@ -200,11 +184,13 @@ def reference_check(config, dims, params, client, mix, seed: int,
 @dataclasses.dataclass
 class Options:
     """What tests and the chip-side scripts change about a run: the chip
-    check, the files a cell names, the control's precision, and a hook
-    that gets the engine before it is warmed and served."""
+    check, the files a cell names (the configuration's architecture module
+    among them), the control's precision, and a hook that gets the engine
+    before it is warmed and served."""
 
     require_tpu: bool = True
     config: Optional[Dict[str, Any]] = None
+    architecture: Optional[ModuleType] = None
     benchmark: Optional[Dict[str, Any]] = None
     mix: Optional[Dict[str, Any]] = None
     control: Optional[str] = None
@@ -217,6 +203,7 @@ def run_cell(args: argparse.Namespace, opts: Options) -> Optional[Dict[str, Any]
     bench = opts.benchmark or common.load_benchmark()
     cell = common.find_cell(bench, args.workload)
     config = opts.config or common.load_config(cell["config"])
+    arch = opts.architecture or common.reference_module(config["reference"])
     mix = dict(opts.mix or common.load_traffic(cell["traffic"]))
     if args.rate is not None:
         mix["rate_per_s"] = args.rate
@@ -245,10 +232,11 @@ def run_cell(args: argparse.Namespace, opts: Options) -> Optional[Dict[str, Any]
     from repro.serve.engine import ContinuousConfig
 
     chip_peaks = peaks_mod.peaks(dev.device_kind if opts.require_tpu else "TPU v5 lite")
-    cfg = system_config(config)
-    dims = weights.dims_of(config["model"])
+    cfg = system_config(config, arch)
+    dims = arch.dims_of(config["model"])
     wseed = int(np.random.default_rng([args.seed, 1]).integers(1 << 31))
-    params = weights.make_params(dims, wseed, config["model"]["param_dtype"])
+    params = weights.make_params(arch.layout(dims), wseed,
+                                 config["model"]["param_dtype"])
     jax.block_until_ready(params)
     check_layout(cfg, params)
 
@@ -323,7 +311,7 @@ def run_cell(args: argparse.Namespace, opts: Options) -> Optional[Dict[str, Any]
                     and not (r.finished and r.times[-1] < t0))
 
     limit = config["correct"]["token_gap_limit"]
-    check = reference_check(config, dims, params, client, mix, args.seed,
+    check = reference_check(arch, dims, params, client, mix, args.seed,
                             opts.control)
     # the control is judged as the system is, in its place
     judged = check["gaps"] if opts.control is None else check["control_gaps"]

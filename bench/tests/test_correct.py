@@ -112,7 +112,7 @@ def test_compile_inside_the_window_is_not_correct():
 
 
 def test_moe_prefill_needs_a_program_per_prompt_length():
-    """Why the MoE configuration has no cell: its eager prefill is keyed
+    """Why the MoE configuration has no cell: its prefill program is keyed
     by the expert capacity of the whole prompt, so the offline mix's
     unrounded prompts reach a prefill program per prompt length, where
     the dense configuration needs 68 in all."""
@@ -130,7 +130,8 @@ def test_moe_prefill_needs_a_program_per_prompt_length():
             cb=SimpleNamespace(prefill_chunk_tokens=config["engine"]["prefill_chunk_tokens"],
                                max_len=config["engine"]["max_len"]),
             block_pool=SimpleNamespace(block_size=config["engine"]["kv_block_size"]),
-            model=build_model(run.system_config(config)))
+            model=build_model(run.system_config(
+                config, common.reference_module(config["reference"]))))
         counts[name] = len(warmup.prefill_shapes(eng, mix))
     assert counts["granite-8b-d8"] == 68
     assert counts["granite-moe-1b-a400m"] > 100 * counts["granite-8b-d8"]

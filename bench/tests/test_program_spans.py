@@ -229,5 +229,6 @@ def test_a_traced_run_of_a_small_cell_reports_the_three_readers():
     metrics = run.run_cell(args, opts)["line"]["metrics"]
     # the CPU trace has no device plane: every span reads idle throughout
     assert metrics["prefill.idle_in_chunk_share"]["value"] == pytest.approx(100.0)
-    assert metrics["prefill.lowerings_per_chunk"]["value"] >= 1
+    # the prefill is jitted once per chunk shape and the warm-up ran them all
+    assert metrics["prefill.lowerings_per_chunk"]["value"] == 0.0
     assert metrics["sched.idle_outside_prefill_ms_per_tick"]["value"] > 0

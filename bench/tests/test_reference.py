@@ -8,27 +8,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import common, weights
+from bench import weights
 from bench.correct import reference_decoder as ref
 from bench.run import system_config
+from bench.tests import arch
 
-DATA = common.BENCH / "tests" / "data"
+NAMES = ["tiny-dense", "tiny-moe", "tiny-dense-bias"]
 
 
-@pytest.mark.parametrize("name", ["tiny-dense", "tiny-moe"])
+@pytest.mark.parametrize("name", NAMES)
 def test_reference_logits_match_the_system_in_float32(name):
     from repro import ops
     from repro.models.registry import build_model
 
-    config = common.load_json(DATA / f"{name}.json")
+    config = arch.config(name)
     config["model"]["compute_dtype"] = "float32"
-    cfg = system_config(config)
-    d = weights.dims_of(config["model"])
-    params = weights.make_params(d, 11)
+    mod = arch.module(config)
+    cfg = system_config(config, mod)
+    d = mod.dims_of(config["model"])
+    params = weights.make_params(mod.layout(d), 11)
     tokens = np.random.default_rng(0).integers(0, d["V"], 70).astype(np.int32)
     with ops.use(attention="reference"), jax.default_matmul_precision("highest"):
         want = np.asarray(build_model(cfg).forward(params, jnp.asarray(tokens)[None])[0])
-    got = np.asarray(ref.logits(params, tokens, d))
+    got = np.asarray(mod.logits(params, tokens, d))
     np.testing.assert_allclose(got, want[:, : d["V"]], rtol=2e-4, atol=2e-4)
 
 
@@ -44,9 +46,9 @@ def test_star_softmax_matches_the_system_engine():
 
 
 def test_lower_precision_moves_the_logits():
-    config = common.load_json(DATA / "tiny-dense.json")
-    d = weights.dims_of(config["model"])
-    params = weights.make_params(d, 2)
+    config = arch.config("tiny-dense")
+    d = ref.dims_of(config["model"])
+    params = weights.make_params(ref.layout(d), 2)
     tokens = np.arange(40, dtype=np.int32)
     hi = np.asarray(ref.logits(params, tokens, d))
     lo = np.asarray(ref.logits(params, tokens, d, compute="float8_e4m3fn"))
@@ -57,10 +59,10 @@ def test_lower_precision_moves_the_logits():
 def test_weights_have_the_system_layout():
     from bench.run import check_layout
 
-    for name in ("tiny-dense", "tiny-moe"):
-        config = common.load_json(DATA / f"{name}.json")
-        d = weights.dims_of(config["model"])
-        check_layout(system_config(config), weights.make_params(d, 1))
+    for name in NAMES:
+        config = arch.config(name)
+        mod = arch.module(config)
+        params = weights.make_params(mod.layout(mod.dims_of(config["model"])), 1)
+        check_layout(system_config(config, mod), params)
     with pytest.raises(SystemExit):
-        bad = dataclasses.replace(system_config(config), d_ff=16)
-        check_layout(bad, weights.make_params(d, 1))
+        check_layout(dataclasses.replace(system_config(config, mod), d_ff=16), params)

@@ -88,11 +88,12 @@ def test_recorded_trace_reduces_to_the_numbers_read_by_hand(recorded):
 
 
 def test_metric_readers_on_the_recorded_trace(recorded):
-    from bench import peaks, weights
+    from bench import peaks
+    from bench.correct import reference_decoder
     from bench.drive import Step
     from bench.readings import Readings
 
-    dims = weights.dims_of(common.load_config("granite-moe-1b-a400m")["model"])
+    dims = reference_decoder.dims_of(common.load_config("granite-moe-1b-a400m")["model"])
     # 29 ticks of 32 decoding slots, 700 live rows each (a stand-in count)
     steps = [Step(0.0, 0.0, 32, 32 * 700) for _ in range(29)]
     ctx = Readings(dims=dims, num_slots=32, kv_itemsize=2, act_itemsize=2,

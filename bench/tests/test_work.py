@@ -5,14 +5,15 @@ published widths."""
 import numpy as np
 import pytest
 
-from bench import common, peaks, weights
+from bench import common, peaks
+from bench.correct import reference_decoder
 
 MFU = common.metric_reader("decode.mfu")
 ROOF = common.metric_reader("paged_decode_roofline")
 
 
 def dims(name):
-    return weights.dims_of(common.load_config(name)["model"])
+    return reference_decoder.dims_of(common.load_config(name)["model"])
 
 
 def test_granite_8b_matmul_params_per_token():
@@ -40,8 +41,9 @@ def test_matmul_params_agree_with_the_system_param_shapes():
 
     for name in ("granite-8b-d8", "granite-moe-1b-a400m"):
         config = common.load_config(name)
-        d = weights.dims_of(config["model"])
-        tree = shape_tree(build_model(system_config(config)).param_specs())
+        d = reference_decoder.dims_of(config["model"])
+        cfg = system_config(config, reference_decoder)
+        tree = shape_tree(build_model(cfg).param_specs())
         b = tree["blocks"]
         n = sum(int(np.prod(b["attn"][k].shape)) for k in ("wq", "wk", "wv", "wo"))
         if "moe" in b:

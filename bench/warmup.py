@@ -1,9 +1,10 @@
 """Warm every program a cell's traffic will run, and only those.
 
-The engine prefills eagerly: each chunk of a prompt calls the model's
-``prefill`` (first chunk) or ``prefill_extend`` (later chunks), whose
-programs are keyed by the chunk length, the staging cache's rows and, for
-MoE, the expert capacity of the whole prompt.  ``prefill_shapes`` lists
+Each chunk of a prompt calls the model's ``prefill`` (first chunk) or
+``prefill_extend`` (later chunks), each jitted once per chunk shape: a
+program is keyed by the chunk length, the staging cache's rows and, for
+MoE, the expert capacity of the whole prompt.  The direct calls here
+reach the same jit cache entries as the engine's own.  ``prefill_shapes`` lists
 every such key the mix can reach; ``warm_prefill`` runs each once.
 ``warm_engine`` then serves one short request per staging width through
 the engine itself, which compiles its decode tick, pool write, table push
